@@ -23,9 +23,8 @@ Three families of helpers live here (docs/sharding.md is the guide):
   payload and scales co-sharded at 16-lane block granularity
   (``QTensor.with_sharding`` enforces the invariant).
 
-:func:`shard_map` is the one version-compat wrapper every packed-operand
-collective path uses (``jax.shard_map`` with ``check_vma`` on new jax,
-``jax.experimental.shard_map`` with ``check_rep`` on 0.4.x).
+:func:`shard_map` is the one wrapper every packed-operand collective
+path uses (``jax.shard_map`` with the replication check off).
 """
 from __future__ import annotations
 
@@ -47,19 +46,14 @@ __all__ = [
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Replication-check-off ``shard_map`` across jax versions.
+    """``jax.shard_map`` with the replication check off.
 
     Every in-repo use replicates operands over the axes a spec omits (the
     bodies are deterministic, so outputs really are replicated there), but
-    the static replication checker cannot always prove it — so it is
-    disabled, under whichever keyword this jax spells it.
+    the static replication checker cannot always prove it.
     """
-    if hasattr(jax, "shard_map"):  # jax >= 0.6: top-level, check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def prepend_pod(spec_tree):

@@ -6,13 +6,15 @@ intermediate HBM round-trips.  Groups (default 16, the quantization block)
 transform independently, so the kernel tiles rows and keeps the full feature
 extent resident.
 
-``fwht_rows_math`` is the shared sign-flip + butterfly body: the standalone
-kernel, the fused W4A4 GEMM prologue (``mixfp4_gemm_w4a4_fused(rht_signs=)``)
-and the serve-time per-row scale derivation in ``core.qtensor`` all call it,
-so the transformed values — and therefore the dual-format select and the
-row amax — cannot drift between the fused and composed paths.  Every op in
-it is an elementwise f32 add/sub/multiply (no reductions, no FMA
-contraction), so in-kernel and plain-jnp evaluations are bit-identical.
+``fwht_rows_math`` is the sign-flip + butterfly body on natural rows (the
+serve-time per-row scale derivation in ``core.qtensor`` calls it);
+``fwht_split_math`` is the same butterfly on rows split into even/odd
+elements (``kernels.lanes``), which the standalone kernel and the fused
+W4A4 GEMM prologue (``mixfp4_gemm_w4a4_fused(rht_signs=)``) run.  Both
+apply the same elementwise f32 adds/subs/multiplies to the same operands
+(no reductions, no FMA contraction), so the transformed values — and
+therefore the dual-format select and the row amax — are bit-identical
+between the fused and composed paths.
 """
 from __future__ import annotations
 
@@ -22,7 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["fwht_rows", "fwht_rows_math"]
+from repro.kernels import lanes
+from repro.kernels.mixfp4_quant import row_tile
+
+__all__ = ["fwht_rows", "fwht_rows_math", "fwht_split_math"]
 
 
 def fwht_rows_math(x: jax.Array, signs: jax.Array, group: int) -> jax.Array:
@@ -45,10 +50,44 @@ def fwht_rows_math(x: jax.Array, signs: jax.Array, group: int) -> jax.Array:
     return x.reshape(bm, k)
 
 
-def _fwht_kernel(x_ref, s_ref, o_ref, *, group: int):
-    x = fwht_rows_math(x_ref[...].astype(jnp.float32),
-                       s_ref[...].astype(jnp.float32), group)
-    o_ref[...] = x.astype(o_ref.dtype)
+def fwht_split_math(xe: jax.Array, xo: jax.Array, se: jax.Array,
+                    so: jax.Array, group: int):
+    """``fwht_rows_math`` on split rows (``kernels.lanes``): ``xe``/``xo``
+    are the even/odd elements of f32 rows, ``se``/``so`` the matching sign
+    halves, shape (1, w).  Natural index ``2i + h`` lives on lane ``i`` of
+    half ``h``, so the first stage pairs the halves elementwise and stage
+    ``h >= 2`` pairs lane ``i`` with lane ``i ^ (h/2)`` — the same adds and
+    subtracts, on the same operands, as the natural-layout butterfly."""
+    xe = xe * se
+    xo = xo * so
+    xe, xo = xe + xo, xe - xo
+    lane = jax.lax.broadcasted_iota(jnp.int32, xe.shape, xe.ndim - 1)
+    h = 2
+    while h < group:
+        low = (lane & (h // 2)) == 0
+        pe = lanes.xor_partner(xe, h // 2)
+        po = lanes.xor_partner(xo, h // 2)
+        xe = jnp.where(low, xe + pe, pe - xe)
+        xo = jnp.where(low, xo + po, po - xo)
+        h *= 2
+    norm = group ** -0.5
+    return xe * norm, xo * norm
+
+
+def _fwht_kernel(xe_ref, xo_ref, se_ref, so_ref, oe_ref, oo_ref, *,
+                 group: int):
+    oe, oo = fwht_split_math(
+        xe_ref[...].astype(jnp.float32), xo_ref[...].astype(jnp.float32),
+        se_ref[...].astype(jnp.float32), so_ref[...].astype(jnp.float32),
+        group)
+    oe_ref[...] = oe.astype(oe_ref.dtype)
+    oo_ref[...] = oo.astype(oo_ref.dtype)
+
+
+def _pick_bm(m: int, k: int) -> int:
+    """Row tile: a 512 KiB f32 tile keeps the split halves and the
+    butterfly temporaries well inside the scoped VMEM limit."""
+    return row_tile(m, k, 512 * 1024)
 
 
 @functools.partial(jax.jit, static_argnames=("group", "bm", "interpret"))
@@ -75,18 +114,18 @@ def fwht_rows(
         raise ValueError(
             f"signs must have shape ({k},), got {signs.shape}")
     if bm is None:
-        bm = max(1, min(256, (4 * 1024 * 1024 // 8) // max(k, 1)))
-        while m % bm and bm > 1:
-            bm //= 2
-    assert m % bm == 0
-    return pl.pallas_call(
+        bm = _pick_bm(m, k)
+    xe, xo = lanes.split_even_odd(x)
+    se, so = lanes.split_even_odd(signs.reshape(1, k))
+    row = pl.BlockSpec((bm, k // 2), lambda i: (i, 0))
+    sign = pl.BlockSpec((1, k // 2), lambda i: (0, 0))
+    half = jax.ShapeDtypeStruct((m, k // 2), x.dtype)
+    oe, oo = pl.pallas_call(
         functools.partial(_fwht_kernel, group=group),
-        grid=(m // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, k), x.dtype),
+        grid=(pl.cdiv(m, bm),),
+        in_specs=[row, row, sign, sign],
+        out_specs=[row, row],
+        out_shape=[half, half],
         interpret=interpret,
-    )(x, signs.reshape(1, k))
+    )(xe, xo, se, so)
+    return lanes.merge_even_odd(oe, oo)

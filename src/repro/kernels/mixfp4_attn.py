@@ -7,7 +7,7 @@ off if the packed representation is consumed *directly* by the attention
 read — dequantizing the whole cache back to bf16 in HBM before every step
 would spend the saved bandwidth immediately.  This kernel streams the
 packed K/V blocks HBM->VMEM, runs the same branch-free Fig. 9 dual-codebook
-decode as ``mixfp4_gemm`` (shared ``_decode_scales``/``_decode_nibbles``)
+decode as ``mixfp4_gemm`` (shared ``_decode_nibbles``/``_expand_scales``)
 on 16-lane blocks in VMEM, and computes masked online-softmax attention
 (flash-decoding) for one query token per sequence.  No dense bf16 copy of
 the cache ever exists in HBM.
@@ -23,11 +23,16 @@ Layout (matches the 1-D ``BlockLayout1D(-1, 16)`` QTensor KV cache built by
 
 Grid: (B, S/bs) with the key-block loop innermost; the running
 (max, sum, acc) flash state lives in VMEM scratch across the key loop and
-the output row is emitted on the last block.  GQA queries reshape to
-(Hkv, group, dh) so each kv head's packed blocks are decoded exactly once
-per step.  Masking covers ragged per-slot lengths, sliding windows and the
-S padding the ``ops`` entry may add; ``softcap`` is a compile-time constant
-(it is an arch property, not a per-layer one).
+the output row is emitted on the last block.  The kernel sees a key block
+as 2-D (bs, Hkv*dh//2) bytes (a free reshape of the cache) whose low and
+high nibbles are the even and odd head dims; the queries arrive split the
+same way and block-diagonal over kv heads, so each kv head's packed
+blocks are decoded exactly once per step and every product is a 2-D
+matmul.  The lengths, the window and the block table ride scalar
+prefetch, the two per-tensor scales SMEM.  Masking covers ragged per-slot
+lengths, sliding windows and the S padding the ``ops`` entry may add;
+``softcap`` is a compile-time constant (it is an arch property, not a
+per-layer one).
 
 ``mixfp4_attn_decode_paged`` is the same flash loop over a *paged* pool
 (``serving/kvpool.py``): K/V slabs are (P, page_len, Hkv, ...) physical
@@ -48,7 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mixfp4_gemm import _decode_nibbles, _decode_scales
+from repro.kernels import lanes
+from repro.kernels.mixfp4_gemm import _decode_nibbles, _expand_scales
 
 __all__ = ["mixfp4_attn_decode", "mixfp4_attn_decode_paged"]
 
@@ -56,117 +62,185 @@ _G = 16
 _NEG_INF = -1e30
 
 
-def _decode_kv_block(payload, scales, s32):
-    """(bs, Hkv, dh//2) packed + (bs, Hkv, dh//16) scale bytes -> f32
-    (bs, Hkv, dh) with block scales and the per-tensor scale fused."""
-    bs, hkv, dh2 = payload.shape
-    dh = 2 * dh2
-    nb = dh // _G
-    lo = payload & 0xF
-    hi = (payload >> 4) & 0xF
-    nib = jnp.stack([lo, hi], axis=-1).reshape(bs, hkv, dh)
-    s, t = _decode_scales(scales)
-    s_full = jnp.broadcast_to(
-        s[..., None], (bs, hkv, nb, _G)).reshape(bs, hkv, dh)
-    t_full = jnp.broadcast_to(
-        t[..., None], (bs, hkv, nb, _G)).reshape(bs, hkv, dh)
-    vals = _decode_nibbles(nib, t_full)
-    return vals * s_full * s32
+def _decode_kv_block(payload, scales):
+    """(bs, Hkv*dh//2) packed + (bs, Hkv*dh//16) scale bytes -> bf16
+    (even dims, odd dims), each (bs, Hkv*dh//2), with the block scales
+    fused (sans the per-tensor scale, so every value — a 4-bit code times
+    an E4M3 scale — is exact in bf16).  Lane ``h*dh//2 + i`` holds dims 2i
+    and 2i+1 of kv head h, and its scale byte is lane
+    ``(h*dh//2 + i) // 8``."""
+    p = payload.astype(jnp.int32)
+    s, t = _expand_scales(scales, _G // 2)
+    return tuple((_decode_nibbles(nib, t) * s).astype(jnp.bfloat16)
+                 for nib in (p & 0xF, p >> 4))
 
 
-def _flash_step(q_ref, kp, ks, vp, vs, kv_len, win, s32_ref,
-                o_ref, acc_ref, m_ref, l_ref, *, softcap: float):
+def _dot_f32_bf16(a, b, dims):
+    """``a @ b`` for f32 ``a`` and bf16-exact ``b`` as two bf16 MXU passes
+    (``a``'s high and low bf16 halves), f32-accurate to about 2^-16."""
+    hi = a.astype(jnp.bfloat16)
+    lo = (a - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
+    return dot(hi, b, dims) + dot(lo, b, dims)
+
+
+def _flash_step(qe_ref, qo_ref, kp, ks, vp, vs, kv_len, win, s32_ref,
+                oe_ref, oo_ref, acce_ref, acco_ref, m_ref, l_ref, *,
+                softcap: float, dh: int):
     """One key-block step of the flash-decoding loop, shared verbatim by
-    the fixed-slot and paged kernels: decode the packed (bs, Hkv, ...)
+    the fixed-slot and paged kernels: decode the packed (bs, Hkv*dh//2)
     K/V block, fold it into the running (max, sum, acc) scratch state, and
-    emit the normalized output row on the last block.  Keeping both
+    emit the normalized output rows on the last block.  Keeping both
     kernels on this one body is what makes paged==fixed a *bitwise*
-    contract rather than an allclose one."""
+    contract rather than an allclose one.
+
+    The queries arrive block-diagonal: row r holds query head r's even /
+    odd dims in the lanes of its kv head and zeros elsewhere, so one 2-D
+    matmul scores every head against its own kv head.  The P·V product
+    fills every kv head's lanes of each row; the caller keeps row r's own
+    head."""
     s_idx = pl.program_id(1)
     ns = pl.num_programs(1)
 
     @pl.when(s_idx == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acce_ref[...] = jnp.zeros_like(acce_ref)
+        acco_ref[...] = jnp.zeros_like(acco_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    bs, hkv, dh2 = kp.shape
-    dh = 2 * dh2
-    h = q_ref.shape[1]
-    g = h // hkv
-
-    k = _decode_kv_block(kp, ks, s32_ref[0, 0])                # (bs,Hkv,dh)
-    q = q_ref[0].astype(jnp.float32).reshape(hkv, g, dh)
-    # scores: per kv head, (g, dh) x (dh, bs) -> (Hkv, g, bs)
-    s = jax.lax.dot_general(
-        q, jnp.transpose(k, (1, 0, 2)),
-        dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * (dh ** -0.5)
+    bs = kp.shape[0]
+    dot = _dot_f32_bf16
+    nt = (((1,), (1,)), ((), ()))
+    nn = (((1,), (0,)), ((), ()))
+    # the per-tensor scales multiply the scores and the emitted rows
+    ke, ko = _decode_kv_block(kp, ks)                          # (bs, W)
+    s = (dot(qe_ref[0], ke, nt) + dot(qo_ref[0], ko, nt)
+         ) * (s32_ref[0] * dh ** -0.5)                         # (H, bs)
     if softcap:
         s = softcap * jnp.tanh(s * (1.0 / softcap))
 
     # decode-position masking: the query sits at position kv_len - 1
-    kpos = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
+    kpos = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     mask = kpos < kv_len
-    mask &= jnp.where(win > 0, kpos > kv_len - 1 - win, True)
+    mask &= (kpos > kv_len - 1 - win) | (win <= 0)
     s = jnp.where(mask, s, _NEG_INF)
 
     # online-softmax update (flash-decoding running state in scratch)
-    m_prev = m_ref[...].reshape(hkv, g, 1)
+    m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    l_new = l_ref[...].reshape(hkv, g, 1) * alpha \
-        + jnp.sum(p, axis=-1, keepdims=True)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
 
-    v = _decode_kv_block(vp, vs, s32_ref[0, 1])                # (bs,Hkv,dh)
-    # (Hkv, g, bs) x (bs, dh) batched over Hkv -> (Hkv, g, dh)
-    pv = jax.lax.dot_general(
-        p, jnp.transpose(v, (1, 0, 2)),
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    acc_new = acc_ref[...].reshape(hkv, g, dh) * alpha + pv
-
-    m_ref[...] = m_new.reshape(h, 1)
-    l_ref[...] = l_new.reshape(h, 1)
-    acc_ref[...] = acc_new.reshape(h, dh)
+    ve, vo = _decode_kv_block(vp, vs)
+    acce_ref[...] = acce_ref[...] * alpha + dot(p, ve, nn)
+    acco_ref[...] = acco_ref[...] * alpha + dot(p, vo, nn)
 
     @pl.when(s_idx == ns - 1)
     def _emit():
         l = l_ref[...]
-        o_ref[0] = acc_ref[...] / jnp.where(l > 0, l, 1.0)
+        l = jnp.where(l > 0, l, 1.0)
+        oe_ref[0] = acce_ref[...] / l * s32_ref[1]
+        oo_ref[0] = acco_ref[...] / l * s32_ref[1]
 
 
-def _attn_decode_kernel(len_ref, win_ref, s32_ref,
-                        q_ref, kp_ref, ks_ref, vp_ref, vs_ref,
-                        o_ref, acc_ref, m_ref, l_ref, *, softcap: float):
-    _flash_step(q_ref, kp_ref[0], ks_ref[0], vp_ref[0], vs_ref[0],
-                len_ref[0, 0], win_ref[0, 0], s32_ref,
-                o_ref, acc_ref, m_ref, l_ref, softcap=softcap)
+def _split_queries(q: jax.Array, hkv: int):
+    """(B, H, dh) -> block-diagonal even/odd query rows (B, H, Hkv*dh//2)
+    and the kv head of each query head."""
+    b, h, dh = q.shape
+    head = jnp.arange(h) // (h // hkv)
+    onehot = (head[:, None] == jnp.arange(hkv)[None, :]).astype(jnp.float32)
+    return tuple(
+        (half[:, :, None, :] * onehot[None, :, :, None]).reshape(
+            b, h, hkv * dh // 2)
+        for half in lanes.split_even_odd(q.astype(jnp.float32))), head
 
 
-def _attn_decode_paged_kernel(bt_ref, len_ref, win_ref, s32_ref, q_ref,
-                              *refs, softcap: float, n_sub: int):
+def _merge_heads(oe, oo, head, hkv: int):
+    """Keep each query row's own kv-head lanes and re-interleave dims."""
+    b, h, w = oe.shape
+
+    def own(o):
+        o = o.reshape(b, h, hkv, w // hkv)
+        return jnp.take_along_axis(o, head[None, :, None, None], 2)[:, :, 0]
+
+    return lanes.merge_even_odd(own(oe), own(oo))
+
+
+def _scalars(lengths, window, k_scale32, v_scale32, b: int):
+    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (b,))
+    win = jnp.asarray(window, jnp.int32).reshape(1)
+    s32 = jnp.stack([jnp.asarray(k_scale32, jnp.float32).reshape(()),
+                     jnp.asarray(v_scale32, jnp.float32).reshape(())])
+    return lengths, win, s32
+
+
+def _flat_kv(a: jax.Array) -> jax.Array:
+    """(..., S, Hkv, x) -> (..., S, Hkv*x): a free row-major reshape."""
+    return a.reshape(*a.shape[:-2], a.shape[-2] * a.shape[-1])
+
+
+def _call(kernel, grid, n_prefetch, kv_specs, q_pair, h, w, interpret,
+          scalars, kv_args):
+    """The pallas_call both kernels share.  ``scalars`` is the
+    ``n_prefetch`` scalar-prefetch operands (block table, lengths, window)
+    then the two K/V scales, which go to SMEM; then come the block-
+    diagonal query halves and the packed K/V operands.  Outputs are the
+    even/odd halves, with the flash state in VMEM scratch."""
+    qmap = lambda i, j, *_: (i, 0, 0)
+    q_spec = pl.BlockSpec((1, h, w), qmap)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=grid,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, q_spec,
+                  *kv_specs],
+        out_specs=[q_spec, q_spec],
+        scratch_shapes=[
+            pltpu.VMEM((h, w), jnp.float32),    # acc, even dims
+            pltpu.VMEM((h, w), jnp.float32),    # acc, odd dims
+            pltpu.VMEM((h, 1), jnp.float32),    # running max
+            pltpu.VMEM((h, 1), jnp.float32),    # running sum
+        ],
+    )
+    out = jax.ShapeDtypeStruct((q_pair[0].shape[0], h, w), jnp.float32)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=[out, out],
+        interpret=interpret,
+    )(*scalars, *q_pair, *kv_args)
+
+
+def _attn_decode_kernel(len_ref, win_ref, s32_ref, qe_ref, qo_ref,
+                        kp_ref, ks_ref, vp_ref, vs_ref, *refs,
+                        softcap: float, dh: int):
+    _flash_step(qe_ref, qo_ref, kp_ref[0], ks_ref[0], vp_ref[0], vs_ref[0],
+                len_ref[pl.program_id(0)], win_ref[0], s32_ref, *refs,
+                softcap=softcap, dh=dh)
+
+
+def _attn_decode_paged_kernel(bt_ref, len_ref, win_ref, s32_ref, qe_ref,
+                              qo_ref, *refs, softcap: float, dh: int,
+                              n_sub: int):
     """Paged flash step: the grid's index maps already gathered the right
     physical pages (via the prefetched block table), so the body only has
     to stitch the ``n_sub`` page-sized sub-blocks back into one logical
-    (bs, Hkv, ...) key block.  Packed bytes concatenate before decode ==
+    (bs, Hkv*dh//2) key block.  Packed bytes concatenate before decode ==
     decode-then-concatenate (the Fig. 9 decode is element-wise per row)."""
     del bt_ref  # consumed by the index maps
-    kv, (o_ref, acc_ref, m_ref, l_ref) = refs[:-4], refs[-4:]
-    assert len(kv) == 4 * n_sub
+    kv, out = refs[:4 * n_sub], refs[4 * n_sub:]
 
     def cat(sub_refs):
-        blocks = [r[0] for r in sub_refs]
+        blocks = [r[0].astype(jnp.int32) for r in sub_refs]
         return blocks[0] if n_sub == 1 else jnp.concatenate(blocks, axis=0)
 
     kp = cat(kv[0 * n_sub:1 * n_sub])
     ks = cat(kv[1 * n_sub:2 * n_sub])
     vp = cat(kv[2 * n_sub:3 * n_sub])
     vs = cat(kv[3 * n_sub:4 * n_sub])
-    _flash_step(q_ref, kp, ks, vp, vs, len_ref[0, 0], win_ref[0, 0],
-                s32_ref, o_ref, acc_ref, m_ref, l_ref, softcap=softcap)
+    _flash_step(qe_ref, qo_ref, kp, ks, vp, vs, len_ref[pl.program_id(0)],
+                win_ref[0], s32_ref, *out, softcap=softcap, dh=dh)
 
 
 @functools.partial(
@@ -209,43 +283,20 @@ def mixfp4_attn_decode(
         bs = tuning.select_attn_key_block(s, hkv, dh)
     bs = min(bs, max(s, 1))
     sp = -(-s // bs) * bs
+    kv = [_flat_kv(a) for a in (k_payload, k_scales, v_payload, v_scales)]
     if sp != s:  # padded rows are masked by `kpos < lengths`
-        pad = ((0, 0), (0, sp - s), (0, 0), (0, 0))
-        k_payload = jnp.pad(k_payload, pad)
-        k_scales = jnp.pad(k_scales, pad)
-        v_payload = jnp.pad(v_payload, pad)
-        v_scales = jnp.pad(v_scales, pad)
+        kv = [jnp.pad(a, ((0, 0), (0, sp - s), (0, 0))) for a in kv]
 
-    lengths = jnp.broadcast_to(
-        jnp.asarray(lengths, jnp.int32), (b,)).reshape(b, 1)
-    win = jnp.asarray(window, jnp.int32).reshape(1, 1)
-    s32 = jnp.stack([jnp.asarray(k_scale32, jnp.float32).reshape(()),
-                     jnp.asarray(v_scale32, jnp.float32).reshape(())]
-                    ).reshape(1, 2)
-
-    grid = (b, sp // bs)
-    kv_spec = pl.BlockSpec((1, bs, hkv, dh2), lambda i, j: (i, j, 0, 0))
-    sc_spec = pl.BlockSpec((1, bs, hkv, dh // _G), lambda i, j: (i, j, 0, 0))
-
-    return pl.pallas_call(
-        functools.partial(_attn_decode_kernel, softcap=softcap),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),      # lengths
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),      # window
-            pl.BlockSpec((1, 2), lambda i, j: (0, 0)),      # scale32s
-            pl.BlockSpec((1, h, dh), lambda i, j: (i, 0, 0)),
-            kv_spec, sc_spec, kv_spec, sc_spec,
-        ],
-        out_specs=pl.BlockSpec((1, h, dh), lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, dh), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((h, dh), jnp.float32),   # acc
-            pltpu.VMEM((h, 1), jnp.float32),    # running max
-            pltpu.VMEM((h, 1), jnp.float32),    # running sum
-        ],
-        interpret=interpret,
-    )(lengths, win, s32, q, k_payload, k_scales, v_payload, v_scales)
+    w = hkv * dh2
+    kv_spec = pl.BlockSpec((1, bs, w), lambda i, j, *_: (i, j, 0))
+    sc_spec = pl.BlockSpec((1, bs, w // 8), lambda i, j, *_: (i, j, 0))
+    (qe, qo), head = _split_queries(q, hkv)
+    lengths, win, s32 = _scalars(lengths, window, k_scale32, v_scale32, b)
+    oe, oo = _call(
+        functools.partial(_attn_decode_kernel, softcap=softcap, dh=dh),
+        (b, sp // bs), 2, [kv_spec, sc_spec, kv_spec, sc_spec], (qe, qo),
+        h, w, interpret, (lengths, win, s32), kv)
+    return _merge_heads(oe, oo, head, hkv)
 
 
 @functools.partial(
@@ -309,52 +360,29 @@ def mixfp4_attn_decode_paged(
             block_tables, ((0, 0), (0, sp // page_len - max_pages)))
     block_tables = jnp.asarray(block_tables, jnp.int32)
 
-    lengths = jnp.broadcast_to(
-        jnp.asarray(lengths, jnp.int32), (b,)).reshape(b, 1)
-    win = jnp.asarray(window, jnp.int32).reshape(1, 1)
-    s32 = jnp.stack([jnp.asarray(k_scale32, jnp.float32).reshape(()),
-                     jnp.asarray(v_scale32, jnp.float32).reshape(())]
-                    ).reshape(1, 2)
-
-    grid = (b, sp // bs)
     if bs >= page_len:
         n_sub, rows = bs // page_len, page_len
 
         def _page_map(t):
-            return lambda i, j, bt: (bt[i, j * n_sub + t], 0, 0, 0)
+            return lambda i, j, bt, *_: (bt[i, j * n_sub + t], 0, 0)
 
         maps = [_page_map(t) for t in range(n_sub)]
     else:
         n_sub, rows = 1, bs
         ipb = page_len // bs
-        maps = [lambda i, j, bt: (bt[i, j // ipb], j % ipb, 0, 0)]
+        maps = [lambda i, j, bt, *_: (bt[i, j // ipb], j % ipb, 0)]
 
-    kv_specs = [pl.BlockSpec((1, rows, hkv, dh2), m) for m in maps]
-    sc_specs = [pl.BlockSpec((1, rows, hkv, dh // _G), m) for m in maps]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, bt: (i, 0)),    # lengths
-            pl.BlockSpec((1, 1), lambda i, j, bt: (0, 0)),    # window
-            pl.BlockSpec((1, 2), lambda i, j, bt: (0, 0)),    # scale32s
-            pl.BlockSpec((1, h, dh), lambda i, j, bt: (i, 0, 0)),
-            *kv_specs, *sc_specs, *kv_specs, *sc_specs,
-        ],
-        out_specs=pl.BlockSpec((1, h, dh), lambda i, j, bt: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, dh), jnp.float32),   # acc
-            pltpu.VMEM((h, 1), jnp.float32),    # running max
-            pltpu.VMEM((h, 1), jnp.float32),    # running sum
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _attn_decode_paged_kernel, softcap=softcap, n_sub=n_sub),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dh), jnp.float32),
-        interpret=interpret,
-    )(block_tables, lengths, win, s32, q,
-      *([k_payload] * n_sub), *([k_scales] * n_sub),
-      *([v_payload] * n_sub), *([v_scales] * n_sub))
+    w = hkv * dh2
+    kv_specs = [pl.BlockSpec((1, rows, w), m) for m in maps]
+    sc_specs = [pl.BlockSpec((1, rows, w // 8), m) for m in maps]
+    kp, ks, vp, vs = (_flat_kv(a)
+                      for a in (k_payload, k_scales, v_payload, v_scales))
+    (qe, qo), head = _split_queries(q, hkv)
+    lengths, win, s32 = _scalars(lengths, window, k_scale32, v_scale32, b)
+    oe, oo = _call(
+        functools.partial(_attn_decode_paged_kernel, softcap=softcap,
+                          dh=dh, n_sub=n_sub),
+        (b, sp // bs), 3, [*kv_specs, *sc_specs, *kv_specs, *sc_specs],
+        (qe, qo), h, w, interpret, (block_tables, lengths, win, s32),
+        [kp] * n_sub + [ks] * n_sub + [vp] * n_sub + [vs] * n_sub)
+    return _merge_heads(oe, oo, head, hkv)

@@ -22,21 +22,26 @@ Three entry points:
 Weight layout (from ``pack_weight_qt``): payload (K//2, N) uint8 with two
 K-consecutive nibbles per byte; scales (K//16, N//16) uint8 for the paper's
 2-D 16x16 weight tiles.  Activation layout (W4A4): payload (M, K//2), scales
-(M, K//16) — 1-D blocks along the contraction axis.
+(M, K//16) — 1-D blocks along the contraction axis.  The low nibble of a
+byte is the even K index, so the kernels work on the even/odd split of K
+(``kernels.lanes``): dense activations arrive as their even and odd K
+columns, a decoded weight tile is its even and odd K rows, and each K step
+is ``dot(x_even, w_even) + dot(x_odd, w_odd)`` — no lane interleave, which
+the TPU compiler cannot lower.
 
 Grid and streaming: the grid is (M/bm, N/bn) with the K loop INSIDE the
-kernel.  Packed weight payload/scale slabs (and the activation tile) live
-in HBM (`memory_space=ANY`) and are streamed into two VMEM slots with
-manual async copies — the next K slab's DMA is issued before the current
-slab is consumed (double buffering), and the f32 accumulator block never
-leaves VMEM scratch, replacing the historical 3-D-grid output-revisit
-pattern.  Accumulation remains K-ordered (`acc += dot(x_k, w_k) * s32` per
-K step), so the fused and two-dispatch paths are bitwise-comparable.
+kernel.  The activation and weight payload slabs live in HBM and are
+streamed into two VMEM slots with manual async copies — the next K slab's
+DMA is issued before the current slab is consumed (double buffering) —
+while the grid pipeline brings each cell's scale bytes into VMEM; the f32
+accumulator block never leaves VMEM scratch.  Accumulation is K-ordered
+(``acc += (dot_even + dot_odd) * s32`` per K step), so the fused and
+two-dispatch paths are bitwise-comparable.
 
 The fused prologue is bitwise-identical to the composition by construction:
 ``quant_block_kernel_math`` returns values already ON the 4-bit lattice and
 an E4M3-valued block scale, the nibble encode/decode round trip is exact on
-both lattices, and the scale byte's pack/unpack is a bitcast — so
+both lattices, and the scale byte's pack/unpack is exact — so
 ``(q * s8).astype(bfloat16)`` equals what ``_expand_act_tile`` reconstructs
 from the packed bytes, element for element.
 """
@@ -49,7 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fwht import fwht_rows_math
+from repro.kernels import lanes
+from repro.kernels.fwht import fwht_split_math
 from repro.kernels.mixfp4_quant import quant_block_kernel_math
 
 __all__ = ["mixfp4_gemm_w4a16", "mixfp4_gemm_w4a4", "mixfp4_gemm_w4a4_fused"]
@@ -58,136 +64,130 @@ _G = 16
 
 
 def _decode_scales(scale_bytes: jax.Array):
-    """scale byte {T | e4m3[6:0]} -> (f32 scale, bool T)."""
-    t = (scale_bytes >> 7).astype(jnp.uint8)
-    s = jax.lax.bitcast_convert_type(
-        (scale_bytes & 0x7F).astype(jnp.uint8), jnp.float8_e4m3fn
-    ).astype(jnp.float32)
-    return s, t
+    """scale byte {T | e4m3[6:0]} -> (f32 scale, int32 T), decoded from the
+    integer fields (the v5e has no fp8 convert)."""
+    b = scale_bytes.astype(jnp.int32)
+    mag = b & 0x7F
+    e = mag >> 3
+    m = mag & 7
+    normal = jax.lax.bitcast_convert_type(((e + 120) << 23) | (m << 20),
+                                          jnp.float32)
+    sub = m.astype(jnp.float32) * 2.0**-9
+    s = jnp.where(e == 0, sub, normal)
+    s = jnp.where(mag == 0x7F, jnp.nan, s)         # E4M3fn's only NaN
+    return s, b >> 7
 
 
 def _decode_nibbles(nib: jax.Array, t_full: jax.Array) -> jax.Array:
-    """Fig. 9 unified decode, gather-free.
+    """Fig. 9 unified decode, gather-free, from int32 fields.
 
-    E2M1 path: value = (1 + m/2) * 2^(e-1), subnormal m/2 at e=0 — computed
-    with two selects and an exp2 (the 'shift path').
+    E2M1 path: magnitudes {0, .5, 1, 1.5, 2, 3, 4, 6} for payload 0..7 —
+    ``p/2`` below 4, ``p-2`` up to 6, then 6 (the 'shift path').
     E1M2 path: effective value == integer payload (the x2 remap folds in).
     """
-    sign = 1.0 - 2.0 * ((nib >> 3) & 1).astype(jnp.float32)
-    p = (nib & 0x7).astype(jnp.float32)
-    e = jnp.floor(p * 0.5)          # payload >> 1, as float
-    mbit = p - 2.0 * e              # payload & 1
-    v_e2m1 = jnp.where(
-        p < 2.0, 0.5 * mbit,
-        jnp.exp2(e - 1.0) * (1.0 + 0.5 * mbit),
-    )
-    v = jnp.where(t_full.astype(bool), p, v_e2m1)
-    return sign * v
+    nib = nib.astype(jnp.int32)
+    p = nib & 0x7
+    pf = p.astype(jnp.float32)
+    v_e2m1 = jnp.where(p < 4, 0.5 * pf, jnp.where(p < 7, pf - 2.0, 6.0))
+    v = jnp.where(t_full != 0, pf, v_e2m1)
+    return jnp.where((nib & 0x8) != 0, -v, v)
+
+
+def _expand_scales(scale_bytes: jax.Array, factor: int):
+    """(r, c) scale bytes -> f32 (scale, type) on (r, c * factor) lanes.
+    The type rides in the sign through one exact 0/1 MXU expansion."""
+    s, t = _decode_scales(scale_bytes)
+    st = lanes.expand_lanes(jnp.where(t != 0, -s, s), factor)
+    return jnp.abs(st), (st < 0).astype(jnp.int32)
 
 
 def _expand_weight_tile(wp, ws, bk: int, bn: int):
     """Decode a packed weight tile: payload (bk//2, bn) + scales
-    (bk//16, bn//16) -> bf16 (bk, bn) with scales fused (sans scale32)."""
-    lo = wp & 0xF
-    hi = (wp >> 4) & 0xF
-    nib = jnp.stack([lo, hi], axis=1).reshape(bk, bn)
-    s, t = _decode_scales(ws)
-    # broadcast per-tile scale/type over the 16x16 tile extent
-    s_full = jnp.broadcast_to(
-        s[:, None, :, None], (bk // _G, _G, bn // _G, _G)).reshape(bk, bn)
-    t_full = jnp.broadcast_to(
-        t[:, None, :, None], (bk // _G, _G, bn // _G, _G)).reshape(bk, bn)
-    vals = _decode_nibbles(nib, t_full)
-    return (vals * s_full).astype(jnp.bfloat16)
+    (bk//16, bn//16) -> bf16 (even K rows, odd K rows), each (bk//2, bn),
+    with the block scales fused (sans scale32).  Payload row r holds K rows
+    2r (low nibble) and 2r+1 (high nibble), both in scale row r // 8."""
+    p = wp.astype(jnp.int32)
+    s, t = _expand_scales(ws, _G)                      # (bk//16, bn)
+    rows = bk // _G
+
+    def down8(a):                                      # each row x 8
+        return jnp.broadcast_to(a[:, None, :], (rows, 8, bn)).reshape(
+            bk // 2, bn)
+
+    s, t = down8(s), down8(t)
+    return tuple((_decode_nibbles(nib, t) * s).astype(jnp.bfloat16)
+                 for nib in (p & 0xF, p >> 4))
 
 
-def _expand_act_tile(xp, xs, bm: int, bk: int):
+def _expand_act_tile(xp, xs):
     """Decode packed activations: payload (bm, bk//2) + scales (bm, bk//16)
-    -> bf16 (bm, bk) with 1-D block scales fused (sans scale32)."""
-    lo = xp & 0xF
-    hi = (xp >> 4) & 0xF
-    nib = jnp.stack([lo, hi], axis=-1).reshape(bm, bk)
-    s, t = _decode_scales(xs)
-    s_full = jnp.broadcast_to(s[:, :, None], (bm, bk // _G, _G)).reshape(bm, bk)
-    t_full = jnp.broadcast_to(t[:, :, None], (bm, bk // _G, _G)).reshape(bm, bk)
-    vals = _decode_nibbles(nib, t_full)
-    return (vals * s_full).astype(jnp.bfloat16)
+    -> bf16 (even K, odd K) halves, each (bm, bk//2), block scales fused
+    (sans scale32)."""
+    p = xp.astype(jnp.int32)
+    s, t = _expand_scales(xs, _G // 2)
+    return tuple((_decode_nibbles(nib, t) * s).astype(jnp.bfloat16)
+                 for nib in (p & 0xF, p >> 4))
 
 
-def _quantize_act_tile(x: jax.Array, inv_s32: jax.Array, bm: int, bk: int):
-    """Fused prologue: quantize a dense f32 x tile to MixFP4 rows in-VMEM
-    (Alg. 1 dual-format select via the shared ``quant_block_kernel_math``)
-    and emit the SAME bf16 values the packed decode path reconstructs —
-    ``q`` is exactly decode(encode(q)) on both lattices and ``s8`` is
-    already E4M3-valued, so ``(q * s8).astype(bf16)`` is bitwise what
-    ``_expand_act_tile`` returns for the two-dispatch composition."""
-    xs = (x.astype(jnp.float32) * inv_s32).reshape(bm, bk // _G, _G)
-    q, s8, _t = quant_block_kernel_math(xs)
-    vals = (q * s8[..., None]).reshape(bm, bk)
-    return vals.astype(jnp.bfloat16)
+def _quantize_act_tile(xe, xo, inv_s32):
+    """Fused prologue: quantize a dense f32 x tile, split into even/odd K
+    halves, to MixFP4 rows in VMEM (Alg. 1 via the shared
+    ``quant_block_kernel_math``) and emit the SAME bf16 values the packed
+    decode path reconstructs — ``q`` is exactly decode(encode(q)) on both
+    lattices and ``s8`` is already E4M3-valued, so ``(q * s8).astype(bf16)``
+    is bitwise what ``_expand_act_tile`` returns for the two-dispatch
+    composition."""
+    qe, qo, s8, _t = quant_block_kernel_math(xe * inv_s32, xo * inv_s32)
+    return (qe * s8).astype(jnp.bfloat16), (qo * s8).astype(jnp.bfloat16)
 
 
 # ---------------------------------------------------------------------------
 # Shared double-buffered kernel body
 # ---------------------------------------------------------------------------
 def _stream_gemm_body(mode: str, nk: int, bm: int, bn: int, bk: int,
-                      per_row: bool, group: int,
-                      s32_ref, signs_hbm, x_refs, wp_hbm, ws_hbm, o_ref,
-                      x_slabs, wp_slab, ws_slab, sg_slab, acc_ref, sem):
-    """Grid cell (i, j): stream K slabs of the packed operands HBM->VMEM
-    through two buffer slots, overlapping the next slab's DMA with the
-    current slab's decode + MXU work; the f32 accumulator stays in VMEM
-    scratch and is written to the output block once, after the K loop.
+                      per_row: bool, group: int, s32_ref, ws_ref, xs_ref,
+                      hbm, slabs, o_ref, acc_ref, sem):
+    """Grid cell (i, j): stream K slabs of the operands HBM->VMEM through
+    two buffer slots, overlapping the next slab's DMA with the current
+    slab's decode + MXU work; the f32 accumulator stays in VMEM scratch and
+    is written to the output block once, after the K loop.
+
+    ``hbm``/``slabs`` hold the streamed operands in a fixed order: the
+    activation (dense even/odd K halves, or the packed payload in ``w4a4``
+    mode), the weight payload, and — fused mode with RHT — the even/odd
+    sign halves.  The scale bytes are small; the grid pipeline brings each
+    cell's whole K column of them into VMEM (``ws_ref``, and ``xs_ref`` in
+    ``w4a4`` mode), indexed by K step.  A K step contracts the even halves
+    against the low-nibble weight rows and the odd halves against the
+    high-nibble rows.
 
     ``per_row=True`` reads the scale operand as an (bm, w) row-tile slab
     instead of the (1, w) scalar row: column 0 carries the combined output
     scale (x_row * w per-tensor), column 1 (fused mode) the row's
     activation scale for the prologue, so every output row is scaled by a
-    function of that row alone.  The scalar branch below is untouched —
-    per-tensor callers keep their exact historical op sequence.
+    function of that row alone.
 
-    ``signs_hbm`` (fused mode only) streams the RHT sign diagonal in the
-    same K slabs as the activation and applies the grouped butterfly
-    (``fwht_rows_math``) in VMEM ahead of the quantizer — the transform is
-    group-local and ``bk % group == 0``, so slab-wise application equals
-    the whole-row transform."""
+    The RHT signs (fused mode only) stream in the activation's K slabs and
+    the grouped butterfly (``fwht_split_math``) runs in VMEM ahead of the
+    quantizer — the transform is group-local and ``bk % group == 0``, so
+    slab-wise application equals the whole-row transform."""
     i = pl.program_id(0)
     j = pl.program_id(1)
     if per_row:
         s32 = s32_ref[...][:, 0:1]          # (bm, 1) combined row scales
     else:
         s32 = s32_ref[0, 0]
+    kh = bk // 2
+    rows = pl.ds(i * bm, bm)
+    n_x = 1 if mode == "w4a4" else 2
+    wins = ([lambda kk: (rows, pl.ds(kk * kh, kh))] * n_x
+            + [lambda kk: (pl.ds(kk * kh, kh), pl.ds(j * bn, bn))]
+            + [lambda kk: (slice(None), pl.ds(kk * kh, kh))] * 2)
 
     def dmas(slot, kk):
-        out = []
-        if mode == "w4a4":
-            xp_hbm, xs_hbm = x_refs
-            xp_slab, xs_slab = x_slabs
-            out.append(pltpu.make_async_copy(
-                xp_hbm.at[pl.ds(i * bm, bm), pl.ds(kk * (bk // 2), bk // 2)],
-                xp_slab.at[slot], sem.at[slot, 0]))
-            out.append(pltpu.make_async_copy(
-                xs_hbm.at[pl.ds(i * bm, bm), pl.ds(kk * (bk // _G), bk // _G)],
-                xs_slab.at[slot], sem.at[slot, 1]))
-        else:
-            (x_hbm,) = x_refs
-            (x_slab,) = x_slabs
-            out.append(pltpu.make_async_copy(
-                x_hbm.at[pl.ds(i * bm, bm), pl.ds(kk * bk, bk)],
-                x_slab.at[slot], sem.at[slot, 0]))
-            if signs_hbm is not None:
-                # sem slot 1 is free in the dense-activation modes
-                out.append(pltpu.make_async_copy(
-                    signs_hbm.at[:, pl.ds(kk * bk, bk)],
-                    sg_slab.at[slot], sem.at[slot, 1]))
-        out.append(pltpu.make_async_copy(
-            wp_hbm.at[pl.ds(kk * (bk // 2), bk // 2), pl.ds(j * bn, bn)],
-            wp_slab.at[slot], sem.at[slot, 2]))
-        out.append(pltpu.make_async_copy(
-            ws_hbm.at[pl.ds(kk * (bk // _G), bk // _G),
-                      pl.ds(j * (bn // _G), bn // _G)],
-            ws_slab.at[slot], sem.at[slot, 3]))
-        return out
+        return [pltpu.make_async_copy(src.at[win(kk)], dst.at[slot],
+                                      sem.at[slot, n])
+                for n, (src, dst, win) in enumerate(zip(hbm, slabs, wins))]
 
     for dma in dmas(0, 0):
         dma.start()
@@ -212,16 +212,19 @@ def _stream_gemm_body(mode: str, nk: int, bm: int, bn: int, bk: int,
             dma.wait()
 
         if mode == "w4a16":
-            x = x_slabs[0][cur].astype(jnp.bfloat16)
+            xe = slabs[0][cur].astype(jnp.bfloat16)
+            xo = slabs[1][cur].astype(jnp.bfloat16)
         elif mode == "w4a4":
-            x = _expand_act_tile(x_slabs[0][cur], x_slabs[1][cur], bm, bk)
+            xe, xo = _expand_act_tile(slabs[0][cur], xs_ref[kk])
         else:
-            xd = x_slabs[0][cur]
-            if signs_hbm is not None:
-                xd = fwht_rows_math(xd, sg_slab[cur], group)
-            x = _quantize_act_tile(xd, inv_s32, bm, bk)
-        w = _expand_weight_tile(wp_slab[cur], ws_slab[cur], bk, bn)
-        acc = jax.lax.dot(x, w, preferred_element_type=jnp.float32)
+            xe, xo = slabs[0][cur], slabs[1][cur]
+            if len(slabs) > 3:
+                xe, xo = fwht_split_math(xe, xo, slabs[3][cur],
+                                         slabs[4][cur], group)
+            xe, xo = _quantize_act_tile(xe, xo, inv_s32)
+        we, wo = _expand_weight_tile(slabs[n_x][cur], ws_ref[0, kk], bk, bn)
+        acc = (jax.lax.dot(xe, we, preferred_element_type=jnp.float32)
+               + jax.lax.dot(xo, wo, preferred_element_type=jnp.float32))
         acc_ref[...] += acc * s32
         return carry
 
@@ -229,67 +232,69 @@ def _stream_gemm_body(mode: str, nk: int, bm: int, bn: int, bk: int,
     o_ref[...] = acc_ref[...]
 
 
-def _stream_gemm_call(mode: str, x_args: tuple, x_scratch: tuple,
-                      s32: jax.Array, payload, scales,
-                      m: int, n: int, k: int,
+def _stream_gemm_call(mode: str, x_args: tuple, s32: jax.Array, payload,
+                      scales, m: int, n: int, k: int,
                       bm: int, bn: int, bk: int, interpret: bool,
                       per_row: bool = False,
                       signs: jax.Array | None = None, group: int = _G):
+    """``x_args`` is the activation pair: the even/odd K halves (M, K//2)
+    of dense rows, or the packed (payload, scales).  Scale bytes are
+    re-laid K-tile-major here, outside the kernel (about 1/32 of the
+    payload bytes), so a K step's scales are one leading-axis index."""
     assert m % bm == 0 and n % bn == 0 and k % bk == 0
     assert bk % _G == 0 and bn % _G == 0
     nk = k // bk
+    kh = bk // 2
     grid = (m // bm, n // bn)
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    kernel = functools.partial(
-        _split_refs_kernel, mode=mode, nk=nk, bm=bm, bn=bn, bk=bk,
-        n_x=len(x_args), per_row=per_row, has_signs=signs is not None,
-        group=group)
+    hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    ws = scales.reshape(nk, bk // _G, n // bn, bn // _G).transpose(2, 0, 1, 3)
+    in_specs = [
+        pl.BlockSpec((1, nk, bk // _G, bn // _G), lambda i, j: (j, 0, 0, 0)),
+    ]
+    inputs = [ws]
+    if mode == "w4a4":
+        xp, xs = x_args
+        in_specs.append(
+            pl.BlockSpec((nk, bm, bk // _G), lambda i, j: (0, i, 0)))
+        inputs.append(xs.reshape(m, nk, bk // _G).transpose(1, 0, 2))
+        hbm_args = [xp]
+        scratch = [pltpu.VMEM((2, bm, kh), jnp.uint8)]
+    else:
+        hbm_args = list(x_args)
+        scratch = [pltpu.VMEM((2, bm, kh), x_args[0].dtype)] * 2
+    hbm_args.append(payload)
+    scratch.append(pltpu.VMEM((2, kh, bn), jnp.uint8))
+    if signs is not None:
+        hbm_args += list(lanes.split_even_odd(signs))
+        scratch += [pltpu.VMEM((2, 1, kh), jnp.float32)] * 2
+    n_hbm = len(hbm_args)
+    scratch += [pltpu.VMEM((bm, bn), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, n_hbm))]
     if per_row:
-        w = s32.shape[1]
-        s32_spec = pl.BlockSpec((bm, w), lambda i, j: (i, 0))
+        s32_spec = pl.BlockSpec((bm, s32.shape[1]), lambda i, j: (i, 0))
     else:
         s32_spec = pl.BlockSpec(s32.shape, lambda i, j: (0, 0))
-    in_specs = [s32_spec] + [any_spec] * (len(x_args) + 2)
-    inputs = (s32, *x_args, payload, scales)
-    scratch = [*x_scratch,
-               pltpu.VMEM((2, bk // 2, bn), jnp.uint8),
-               pltpu.VMEM((2, bk // _G, bn // _G), jnp.uint8)]
-    if signs is not None:
-        in_specs.append(any_spec)
-        inputs = inputs + (signs,)
-        scratch.append(pltpu.VMEM((2, 1, bk), jnp.float32))
-    scratch += [pltpu.VMEM((bm, bn), jnp.float32),
-                pltpu.SemaphoreType.DMA((2, 4))]
+    n_vmem = len(inputs)
+
+    def kernel(s32_ref, *refs):
+        ws_ref = refs[0]
+        xs_ref = refs[1] if mode == "w4a4" else None
+        hbm = refs[n_vmem:n_vmem + n_hbm]
+        o_ref = refs[n_vmem + n_hbm]
+        slabs = refs[n_vmem + n_hbm + 1:n_vmem + 2 * n_hbm + 1]
+        acc_ref, sem = refs[n_vmem + 2 * n_hbm + 1:]
+        _stream_gemm_body(mode, nk, bm, bn, bk, per_row, group, s32_ref,
+                          ws_ref, xs_ref, hbm, slabs, o_ref, acc_ref, sem)
+
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[s32_spec, *in_specs] + [hbm_spec] * n_hbm,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(*inputs)
-
-
-def _split_refs_kernel(s32_ref, *refs, mode: str, nk: int,
-                       bm: int, bn: int, bk: int, n_x: int,
-                       per_row: bool, has_signs: bool, group: int):
-    x_refs = refs[:n_x]
-    wp_hbm, ws_hbm = refs[n_x:n_x + 2]
-    idx = n_x + 2
-    signs_hbm = refs[idx] if has_signs else None
-    idx += 1 if has_signs else 0
-    o_ref = refs[idx]
-    idx += 1
-    x_slabs = refs[idx:idx + n_x]
-    wp_slab, ws_slab = refs[idx + n_x:idx + n_x + 2]
-    idx += n_x + 2
-    sg_slab = refs[idx] if has_signs else None
-    idx += 1 if has_signs else 0
-    acc_ref, sem = refs[idx:idx + 2]
-    _stream_gemm_body(mode, nk, bm, bn, bk, per_row, group, s32_ref,
-                      signs_hbm, x_refs, wp_hbm, ws_hbm, o_ref, x_slabs,
-                      wp_slab, ws_slab, sg_slab, acc_ref, sem)
+    )(s32, *inputs, *hbm_args)
 
 
 # ---------------------------------------------------------------------------
@@ -318,8 +323,8 @@ def mixfp4_gemm_w4a16(
     s32 = scale32.reshape(1, 1).astype(jnp.float32)
     xb = x.astype(jnp.bfloat16)     # same single rne rounding as in-kernel
     return _stream_gemm_call(
-        "w4a16", (xb,), (pltpu.VMEM((2, bm, bk), jnp.bfloat16),),
-        s32, payload, scales, m, n, k, bm, bn, bk, interpret)
+        "w4a16", lanes.split_even_odd(xb), s32, payload, scales,
+        m, n, k, bm, bn, bk, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -364,11 +369,8 @@ def mixfp4_gemm_w4a4(
         s32 = (x_scale32.astype(jnp.float32)
                * scale32.astype(jnp.float32)).reshape(1, 1)
     return _stream_gemm_call(
-        "w4a4", (x_payload, x_scales),
-        (pltpu.VMEM((2, bm, bk // 2), jnp.uint8),
-         pltpu.VMEM((2, bm, bk // _G), jnp.uint8)),
-        s32, payload, scales, m, n, k, bm, bn, bk, interpret,
-        per_row=per_row)
+        "w4a4", (x_payload, x_scales), s32, payload, scales,
+        m, n, k, bm, bn, bk, interpret, per_row=per_row)
 
 
 # ---------------------------------------------------------------------------
@@ -420,7 +422,7 @@ def mixfp4_gemm_w4a4_fused(
     row i (the serve-time batch-independence contract).
 
     ``rht_signs`` (with ``per_row``) fuses the grouped random Hadamard
-    transform (``core.hadamard.rht`` semantics, shared ``fwht_rows_math``
+    transform (``core.hadamard.rht`` semantics, the ``fwht_split_math``
     butterfly) ahead of the quantizer in the same VMEM pass: signs stream
     in the activation's K slabs, the transform is group-local and
     ``bk % rht_group == 0``, so the result is bitwise what
@@ -461,7 +463,6 @@ def mixfp4_gemm_w4a4_fused(
         s32 = jnp.stack([xs32 * scale32.astype(jnp.float32).reshape(()),
                          xs32]).reshape(1, 2)
     return _stream_gemm_call(
-        "w4a4_fused", (x.astype(jnp.float32),),
-        (pltpu.VMEM((2, bm, bk), jnp.float32),),
-        s32, payload, scales, m, n, k, bm, bn, bk, interpret,
+        "w4a4_fused", lanes.split_even_odd(x.astype(jnp.float32)), s32,
+        payload, scales, m, n, k, bm, bn, bk, interpret,
         per_row=per_row, signs=signs, group=rht_group)

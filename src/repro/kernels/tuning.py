@@ -20,7 +20,9 @@ degrades to 16-wide tiles.
 Contracts the selection upholds (tested in ``tests/test_tuning.py``):
 
 * every choice's :func:`vmem_footprint` fits :data:`VMEM_BUDGET`,
-* a padded dim >= 64 never gets a tile below 64 lanes (``MIN_WIDE``),
+* a padded dim >= 128 never gets a tile below 128 lanes (``MIN_WIDE``),
+* every tile is one the TPU compiler accepts: bn a multiple of 128 and bk
+  of 256, or the whole dimension,
 * ``bk`` is chosen independently of N, so a column-parallel shard of a
   weight keeps the single-device K tiling — the bitwise-identity contract
   of ``qmm_sharded`` (docs/sharding.md) survives autotuning,
@@ -28,10 +30,8 @@ Contracts the selection upholds (tested in ``tests/test_tuning.py``):
   so continuous-batching batch-size wobble (m = 3, 4, 5, ...) lands on one
   padded M and reuses one compiled kernel instead of re-jitting per m.
 
-Choices are cached per ``(path, padded shape)`` in a process-level table;
-:func:`save_profile` / :func:`load_profile` persist it as JSON (auto-loaded
-from ``$MIXFP4_TUNING_PROFILE`` on first use), so a serving process can pin
-the exact tiling a profiling run validated.
+Choices are cached per ``(path, padded shape)`` in a process-level table
+and come from this code alone.
 
 This module is pure Python on purpose (no jax import): it is consulted at
 trace time from ``core/qtensor.py`` and ``kernels/mixfp4_attn.py`` and must
@@ -40,8 +40,6 @@ never add dispatch-path work or import cycles.
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
 import threading
 
 __all__ = [
@@ -57,9 +55,6 @@ __all__ = [
     "BM_LADDER",
     "clear_cache",
     "cache_info",
-    "save_profile",
-    "load_profile",
-    "PROFILE_ENV",
 ]
 
 _G = 16          # paper block size g (scale granularity)
@@ -76,10 +71,13 @@ VMEM_BUDGET = int(VMEM_BYTES * 0.70)   # leave headroom for Mosaic spills
 DMA_SETUP_S = 1.0e-6     # per-transfer latency: favors fat slabs
 GRID_CELL_S = 1.5e-6     # per grid cell launch/bookkeeping overhead
 
-MIN_WIDE = 64            # padded dims never collapse below 64 lanes
+# Mosaic DMAs whole (8, 128) tiles: a K slab is bk//2 lanes of the split
+# activation halves and weight payload rows, an N slab bn lanes, so a tile
+# is a whole multiple of 128 lanes (bk of 256) or the whole dimension.
+MIN_WIDE = 128           # padded dims never collapse below 128 lanes
 BM_LADDER = (8, 16, 32, 64, 128)
-_BN_CHOICES = (16, 32, 64, 128, 256, 512)
-_BK_CHOICES = (16, 32, 64, 128, 256, 512)
+_BN_CHOICES = (128, 256, 512)
+_BK_CHOICES = (256, 512)
 _SINGLE_TILE_CAP = 512   # whole-dim single tile allowed up to this width
 
 # VPU op counts per value (coarse: selects/shifts/multiplies per element)
@@ -87,7 +85,6 @@ _DECODE_OPS = 12.0       # Fig. 9 dual-codebook decode
 _QUANT_OPS = 40.0        # fused prologue: dual-candidate quantize + argmin
 
 _PATHS = ("w4a16", "w4a4", "w4a4_fused")
-PROFILE_ENV = "MIXFP4_TUNING_PROFILE"
 
 
 def _pad(d: int, t: int) -> int:
@@ -237,7 +234,10 @@ def _select_bk(path: str, m: int, kp: int, bm: int) -> int:
         if best_t is None or t < best_t - 1e-12 or \
                 (abs(t - best_t) <= 1e-12 and bk > best):
             best, best_t = bk, t
-    return best if best is not None else _G
+    if best is None:
+        raise ValueError(f"no K tile of {kp} fits the VMEM budget at "
+                         f"bm={bm}")
+    return best
 
 
 def _select_bn(path: str, m: int, kp: int, np_: int, bm: int, bk: int) -> int:
@@ -250,70 +250,29 @@ def _select_bn(path: str, m: int, kp: int, np_: int, bm: int, bk: int) -> int:
         if best_t is None or t < best_t - 1e-12 or \
                 (abs(t - best_t) <= 1e-12 and bn > best):
             best, best_t = bn, t
-    return best if best is not None else min(np_, _G)
+    if best is None:
+        raise ValueError(f"no N tile of {np_} fits the VMEM budget at "
+                         f"bm={bm}, bk={bk}")
+    return best
 
 
 # ---------------------------------------------------------------------------
-# Process-level cache + optional on-disk profile
+# Process-level cache
 # ---------------------------------------------------------------------------
 _LOCK = threading.Lock()
 _CACHE: dict = {}
 _STATS = {"hits": 0, "misses": 0}
-_PROFILE_CHECKED = False
-
-
-def _maybe_autoload():
-    global _PROFILE_CHECKED
-    if _PROFILE_CHECKED:
-        return
-    _PROFILE_CHECKED = True
-    path = os.environ.get(PROFILE_ENV)
-    if path and os.path.exists(path):
-        load_profile(path)
 
 
 def clear_cache():
-    global _PROFILE_CHECKED
     with _LOCK:
         _CACHE.clear()
         _STATS["hits"] = _STATS["misses"] = 0
-        _PROFILE_CHECKED = True  # an explicit clear opts out of autoload
 
 
 def cache_info() -> dict:
     with _LOCK:
         return {"entries": len(_CACHE), **_STATS}
-
-
-def save_profile(path: str | None = None):
-    """Persist the tuned choices as JSON (``key -> TileChoice tuple``)."""
-    path = path or os.environ.get(PROFILE_ENV)
-    if not path:
-        raise ValueError(f"save_profile needs a path (or ${PROFILE_ENV})")
-    with _LOCK:
-        blob = {"|".join(map(str, k)): list(v.astuple() if
-                                            isinstance(v, TileChoice)
-                                            else (v,))
-                for k, v in _CACHE.items()}
-    with open(path, "w") as f:
-        json.dump(blob, f, indent=1, sort_keys=True)
-
-
-def load_profile(path: str | None = None):
-    """Load a saved profile into the process cache (entries win over fresh
-    scoring: a profiled deployment pins its validated tiling)."""
-    path = path or os.environ.get(PROFILE_ENV)
-    if not path:
-        raise ValueError(f"load_profile needs a path (or ${PROFILE_ENV})")
-    with open(path) as f:
-        blob = json.load(f)
-    with _LOCK:
-        for key_s, vals in blob.items():
-            parts = key_s.split("|")
-            key = tuple(int(p) if p.lstrip("-").isdigit() else p
-                        for p in parts)
-            _CACHE[key] = (TileChoice(*vals) if len(vals) == 6
-                           else int(vals[0]))
 
 
 # ---------------------------------------------------------------------------
@@ -337,7 +296,6 @@ def select_tiles(path: str, m: int, kp: int, np_: int) -> TileChoice:
     bm = round_up_rows(m)
     mp = _pad(m, bm)
     key = (group, mp, kp, np_)
-    _maybe_autoload()
     with _LOCK:
         hit = _CACHE.get(key)
         if hit is not None:
@@ -370,7 +328,6 @@ def select_attn_key_block(s: int, hkv: int, dh: int) -> int:
     padding than it saves in per-block overhead."""
     s = max(int(s), 1)
     key = ("attn", s, hkv, dh)
-    _maybe_autoload()
     with _LOCK:
         hit = _CACHE.get(key)
         if hit is not None:

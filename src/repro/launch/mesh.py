@@ -30,14 +30,22 @@ device state (the dry-run sets XLA_FLAGS before any jax initialisation).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh"]
+
+
+def _mesh(shape, axes):
+    """Auto axes: the model code places values with
+    ``with_sharding_constraint``, which refuses the Explicit axes that
+    ``jax.make_mesh`` defaults to."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):
@@ -55,4 +63,4 @@ def make_host_mesh(data: int | None = None, model: int = 1):
             f"initializes — launch/serve.py --force-host-devices N)")
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))

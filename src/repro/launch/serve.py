@@ -57,6 +57,7 @@ import numpy as np
 from repro import configs
 from repro.core import qtensor
 from repro.core.qgemm import QuantConfig
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.base import build_model, param_count
 from repro.serving.engine import QueueFullError, Request, ServeEngine
@@ -233,6 +234,7 @@ def main(argv=None):
             ap.error("--save-weights requires packed weights; drop --no-pack "
                      "(the checkpoint format is the packed QTensor tree)")
 
+    print(f"[serve] compile cache at {compile_cache.enable()}")
     cfg = (configs.smoke_config(args.arch) if args.smoke
            else configs.full_config(args.arch))
     cfg = cfg.replace(quant=QuantConfig(method=args.quant))

@@ -16,6 +16,7 @@ buffers (KV caches shard over sequence instead — see serving/).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Any, Callable, NamedTuple
@@ -136,18 +137,20 @@ def param_count(values) -> int:
 
 def _active_mesh():
     """The mesh from the enclosing `with mesh:` context, or None."""
-    try:  # newer JAX
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        try:  # deprecated alias
-            m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        except Exception:
-            return None
-    try:
-        return m if (m.axis_names and not m.empty) else None
-    except Exception:
-        return None
+    from jax._src import mesh as mesh_lib
+    m = mesh_lib.thread_resources.env.physical_mesh
+    return m if (m.axis_names and not m.empty) else None
+
+
+def _whole_on_each_device(fn, *args):
+    """Call a Pallas kernel under the ambient mesh, if any.  Mosaic kernels
+    cannot be partitioned automatically, so on a multi-device mesh every
+    device runs the call whole on replicated operands."""
+    m = _active_mesh()
+    if m is None or m.size == 1:
+        return fn(*args)
+    from repro.distributed.sharding import shard_map
+    return shard_map(fn, mesh=m, in_specs=P(), out_specs=P())(*args)
 
 
 # Global sharding regime.
@@ -328,8 +331,11 @@ def qlinear(x: jax.Array, w, ctx: "Ctx", tag: int) -> jax.Array:
                 x2f = x2.astype(jnp.float32)
                 if kp != k:
                     x2f = jnp.pad(x2f, ((0, 0), (0, kp - k)))
-                x2 = ops.rht_rows(x2f, hadamard.serve_signs(kp))
-            qx = qtensor.quantize_rows(x2, pad_to=kp, per_row=per_row)
+                x2 = _whole_on_each_device(
+                    lambda a: ops.rht_rows(a, hadamard.serve_signs(kp)), x2f)
+            qx = _whole_on_each_device(
+                lambda a: qtensor.quantize_rows(a, pad_to=kp,
+                                                per_row=per_row), x2)
             if aq != "mixfp4-qdq":
                 y = (qtensor.qmm_sharded(qx, w, mesh=m) if sharded
                      else qtensor.qmm(qx, w))
@@ -369,6 +375,19 @@ def is_packable_projection(key: str, leaf) -> bool:
             and min(leaf.shape[-2:]) >= 16)
 
 
+@functools.partial(jax.jit, static_argnums=2)
+def _quantize_stack(flat, signs, spec):
+    """Quantize a (L, K, N) stack one (K, N) matrix at a time: the device
+    holds one matrix's quantizer temporaries, not the whole stack's (a
+    gemma2-2b MLP stack is 2.2 GB in f32).  ``signs`` applies the serve-time
+    RHT along K first."""
+    def one(w):
+        if signs is not None:
+            w = hadamard.rht(w, signs, axis=-2, group=16)
+        return qtensor.quantize(w, spec)
+    return jax.lax.map(one, flat)
+
+
 def pack_projections(params, method: str = "mixfp4",
                      block: tuple[int, int] = (16, 16),
                      act_rht: bool = False):
@@ -377,10 +396,13 @@ def pack_projections(params, method: str = "mixfp4",
 
     Leaves with leading batch dims — ``(n_layers, K, N)`` from the
     ``lax.scan`` layout, ``(n_layers, E, K, N)`` for scan-stacked MoE
-    experts — are quantized per trailing matrix under ``vmap``; the result is
-    one QTensor whose children carry the leading dims, which scan/``lax.map``
-    slice transparently.  Returns ``(packed_tree, packed_bytes, dense_bytes)``
-    where the byte counts cover the converted leaves (dense at bf16 rates).
+    experts — are quantized one trailing matrix at a time (``lax.map`` in
+    one jit); the result is one QTensor whose children carry the leading
+    dims, which scan/``lax.map`` slice transparently.  Projection leaves
+    that are already packed QTensors (another engine's tree) pass through
+    (not with ``act_rht``, which must transform the dense weights).
+    Returns ``(packed_tree, packed_bytes, dense_bytes)`` where the byte
+    counts cover the projection leaves (dense at bf16 rates).
 
     ``act_rht=True`` applies the serve-time grouped random Hadamard
     transform along each projection's K axis BEFORE quantizing (signs from
@@ -398,6 +420,7 @@ def pack_projections(params, method: str = "mixfp4",
     signs_used: dict[str, jax.Array] = {}
 
     def convert(w):
+        signs = None
         if act_rht:
             k_ax = w.shape[-2]
             if k_ax % 16:
@@ -406,11 +429,10 @@ def pack_projections(params, method: str = "mixfp4",
                     f"must be a multiple of the RHT group (16)")
             signs = hadamard.serve_signs(k_ax)
             signs_used[str(k_ax)] = signs
-            w = hadamard.rht(w, signs, axis=-2, group=16)
         lead = w.shape[:-2]
         if lead:
             flat = w.reshape((-1,) + w.shape[-2:])
-            qt = jax.vmap(lambda m: qtensor.quantize(m, spec))(flat)
+            qt = _quantize_stack(flat, signs, spec)
             if len(lead) > 1:
                 qt = qtensor.QTensor(
                     qt.payload.reshape(lead + qt.payload.shape[1:]),
@@ -418,14 +440,28 @@ def pack_projections(params, method: str = "mixfp4",
                     qt.scale32.reshape(lead + qt.scale32.shape[1:]),
                     qt.method, qt.layout, qt.shape, qt.dtype)
         else:
+            if signs is not None:
+                w = hadamard.rht(w, signs, axis=-2, group=16)
             qt = qtensor.quantize(w, spec)
         stats["packed"] += qt.nbytes
         stats["dense"] += w.size * 2
         return qt
 
+    def count(qt):
+        if act_rht:
+            raise ValueError(
+                "pack_projections(act_rht=True) transforms the dense "
+                "weights before packing; this projection is already packed")
+        stats["packed"] += qt.nbytes
+        stats["dense"] += 2 * math.prod(qt.shape) * math.prod(
+            qt.payload.shape[:-2])
+        return qt
+
     def walk(node):
         if isinstance(node, dict):
             return {k: (convert(v) if is_packable_projection(k, v)
+                        else count(v) if (k in PROJECTION_KEYS and
+                                          isinstance(v, qtensor.QTensor))
                         else walk(v))
                     for k, v in node.items()}
         return node
@@ -603,7 +639,8 @@ def quantize_kv_rows(kv: jax.Array) -> tuple[jax.Array, jax.Array]:
 
     shape = kv.shape
     flat = kv.reshape(-1, shape[-1]).astype(jnp.float32)
-    payload, scales, _ = ops.quantize_rows(flat, scale32=KV_SCALE32)
+    payload, scales, _ = _whole_on_each_device(
+        lambda a: ops.quantize_rows(a, scale32=KV_SCALE32), flat)
     return (payload.reshape(*shape[:-1], shape[-1] // 2),
             scales.reshape(*shape[:-1], shape[-1] // 16))
 
@@ -672,10 +709,12 @@ def _attn_packed_cached(q, knew, vnew, kv_cache, cache_len, window,
         cks = ck.scales.at[rows, cl_vec].set(ks[:, 0])
         cvp = cv.payload.at[rows, cl_vec].set(vp[:, 0])
         cvs = cv.scales.at[rows, cl_vec].set(vs[:, 0])
-        o = ops.attn_decode_packed(
-            q[:, 0], ckp, cks, cvp, cvs, cl_vec + 1,
-            window=window, softcap=cfg.softcap_attn,
-            k_scale32=ck.scale32, v_scale32=cv.scale32)
+        o = _whole_on_each_device(
+            lambda *a: ops.attn_decode_packed(
+                *a[:6], window=a[6], softcap=cfg.softcap_attn,
+                k_scale32=a[7], v_scale32=a[8]),
+            q[:, 0], ckp, cks, cvp, cvs, cl_vec + 1, jnp.asarray(window),
+            ck.scale32, cv.scale32)
         o = o[:, None].astype(q.dtype)
     else:
         assert cl.ndim == 0, \

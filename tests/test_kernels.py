@@ -199,3 +199,15 @@ def test_quant_rows_zero_rows_pinned_scale32_canonical_scale_bytes():
     b2 = scaling.pack_scale_with_type(jnp.zeros((1,)),
                                       jnp.ones((1,), jnp.uint8))
     assert int(np.asarray(b2)[0]) == 0x00
+
+
+@pytest.mark.parametrize("m", [8, 24, 136])
+def test_row_tiles_obey_tpu_tiling(m):
+    """The quantizer and FWHT row tiles at gemma2-2b's K=2304: a multiple
+    of 8 (the (8, 128) tiling rule) or the whole of M — never the odd
+    heights a halving search lands on (170 -> 85 -> ... -> 2)."""
+    from repro.kernels import fwht, mixfp4_quant
+    for pick in (mixfp4_quant._pick_bm, fwht._pick_bm):
+        bm = pick(m, 2304)
+        assert bm == m or bm % 8 == 0, (pick.__module__, m, bm)
+        assert 0 < bm <= m

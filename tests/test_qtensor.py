@@ -354,3 +354,26 @@ def test_prepad_for_tiles_passes_through_non_2d():
     assert qtensor.prepad_for_tiles(stacked, "w4a16", 4) is stacked
     rows = qtensor.quantize_rows(_rand((4, 32), 3), interpret=True)
     assert qtensor.prepad_for_tiles(rows, "w4a4", 4) is rows
+
+
+def test_pack_projections_stacks_and_passes_packed_leaves_through():
+    """A scan-stacked leaf packs to exactly the per-matrix ``quantize``
+    bytes (one matrix at a time on the device), and a tree whose
+    projections are already packed — another engine's — passes through
+    with the same byte accounting; ``act_rht`` refuses it, since the
+    rotation applies to dense weights."""
+    from repro.models.base import pack_projections
+    w = _rand((3, 64, 48), 11, 0.3)
+    packed, nbytes, dense = pack_projections({"wq": w})
+    spec = QuantSpec("mixfp4", BlockLayout2D())
+    for i in range(3):
+        qt = quantize(w[i], spec)
+        np.testing.assert_array_equal(np.asarray(packed["wq"].payload[i]),
+                                      np.asarray(qt.payload))
+        np.testing.assert_array_equal(np.asarray(packed["wq"].scales[i]),
+                                      np.asarray(qt.scales))
+    again, nbytes2, dense2 = pack_projections(packed)
+    assert again["wq"] is packed["wq"]
+    assert (nbytes2, dense2) == (nbytes, dense)
+    with pytest.raises(ValueError, match="already packed"):
+        pack_projections(packed, act_rht=True)

@@ -21,6 +21,9 @@ allowed to be lost on disk — recovery re-derives them bitwise (greedy
 decode), which is exactly why the assertion is on the FULL stream, not on
 what the journal happened to hold.
 
+This is a CPU tool: it runs three serving processes (JAX_PLATFORMS=cpu,
+the smoke-sized model), and a TPU belongs to one process at a time.
+
 Run locally:  PYTHONPATH=src python tools/restart_smoke.py
 """
 import os
