@@ -127,5 +127,6 @@ def fwht_rows(
         out_specs=[row, row],
         out_shape=[half, half],
         interpret=interpret,
+        name="fwht_rows",
     )(xe, xo, se, so)
     return lanes.merge_even_odd(oe, oo)

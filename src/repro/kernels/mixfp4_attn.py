@@ -183,9 +183,10 @@ def _flat_kv(a: jax.Array) -> jax.Array:
     return a.reshape(*a.shape[:-2], a.shape[-2] * a.shape[-1])
 
 
-def _call(kernel, grid, n_prefetch, kv_specs, q_pair, h, w, interpret,
+def _call(name, kernel, grid, n_prefetch, kv_specs, q_pair, h, w, interpret,
           scalars, kv_args):
-    """The pallas_call both kernels share.  ``scalars`` is the
+    """The pallas_call both kernels share, named ``name`` (the public
+    kernel's name, which a profiler trace shows).  ``scalars`` is the
     ``n_prefetch`` scalar-prefetch operands (block table, lengths, window)
     then the two K/V scales, which go to SMEM; then come the block-
     diagonal query halves and the packed K/V operands.  Outputs are the
@@ -208,7 +209,7 @@ def _call(kernel, grid, n_prefetch, kv_specs, q_pair, h, w, interpret,
     out = jax.ShapeDtypeStruct((q_pair[0].shape[0], h, w), jnp.float32)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=[out, out],
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(*scalars, *q_pair, *kv_args)
 
 
@@ -293,6 +294,7 @@ def mixfp4_attn_decode(
     (qe, qo), head = _split_queries(q, hkv)
     lengths, win, s32 = _scalars(lengths, window, k_scale32, v_scale32, b)
     oe, oo = _call(
+        "mixfp4_attn_decode",
         functools.partial(_attn_decode_kernel, softcap=softcap, dh=dh),
         (b, sp // bs), 2, [kv_spec, sc_spec, kv_spec, sc_spec], (qe, qo),
         h, w, interpret, (lengths, win, s32), kv)
@@ -380,6 +382,7 @@ def mixfp4_attn_decode_paged(
     (qe, qo), head = _split_queries(q, hkv)
     lengths, win, s32 = _scalars(lengths, window, k_scale32, v_scale32, b)
     oe, oo = _call(
+        "mixfp4_attn_decode_paged",
         functools.partial(_attn_decode_paged_kernel, softcap=softcap,
                           dh=dh, n_sub=n_sub),
         (b, sp // bs), 3, [*kv_specs, *sc_specs, *kv_specs, *sc_specs],

@@ -240,7 +240,9 @@ def _stream_gemm_call(mode: str, x_args: tuple, s32: jax.Array, payload,
     """``x_args`` is the activation pair: the even/odd K halves (M, K//2)
     of dense rows, or the packed (payload, scales).  Scale bytes are
     re-laid K-tile-major here, outside the kernel (about 1/32 of the
-    payload bytes), so a K step's scales are one leading-axis index."""
+    payload bytes), so a K step's scales are one leading-axis index.  The
+    kernel is named after the public entry of its mode
+    (``mixfp4_gemm_<mode>``), which names it in a profiler trace."""
     assert m % bm == 0 and n % bn == 0 and k % bk == 0
     assert bk % _G == 0 and bn % _G == 0
     nk = k // bk
@@ -294,6 +296,7 @@ def _stream_gemm_call(mode: str, x_args: tuple, s32: jax.Array, payload,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
+        name=f"mixfp4_gemm_{mode}",
     )(s32, *inputs, *hbm_args)
 
 

@@ -230,6 +230,7 @@ def mixfp4_quant_rows(
             jax.ShapeDtypeStruct((m, k // 2), jnp.uint8),
         ],
         interpret=interpret,
+        name="mixfp4_quant_rows",
     )(s32, xe, xo)
     scales = lane_scales[:, ::_G // 2]
     return payload, scales, (s32[:, 0] if per_row else s32[0, 0])

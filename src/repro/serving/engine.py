@@ -85,7 +85,7 @@ from repro.models.base import ArchConfig, Ctx, build_model, pack_projections
 from repro.serving.faults import InjectedFault, SystemClock
 from repro.serving.journal import JournalError, RequestJournal, replay
 from repro.serving.kvpool import KVPool
-from repro.serving.metrics import MetricsRegistry
+from repro.serving.metrics import MetricsRegistry, span
 from repro.serving.scheduler import ChunkedPrefillScheduler
 from repro.serving.watchdog import StepWatchdog
 
@@ -513,38 +513,44 @@ class ServeEngine:
         """(Re)build the decode/prefill jit closures for the engine's
         CURRENT ``ctx``/``_paged_suffix``.  Called at init and again by the
         degradation rungs (fused -> 2-pass rebinds ctx.act_quant; paged ->
-        fixed-slot drops the block-table operand)."""
-        self._decode = jax.jit(
-            lambda p, t, c, l: self.model.decode_step(p, t, self.ctx, c, l))
+        fixed-slot drops the block-table operand).  The inner functions'
+        names name the programs in a profiler trace (``jit_serve_decode``,
+        ``jit_serve_prefill``, ``jit_serve_chunk_prefill``)."""
+        model = self.model
+
+        def serve_decode(p, t, c, l):
+            return model.decode_step(p, t, self.ctx, c, l)
+
+        self._decode = jax.jit(serve_decode)
         # prefix-caching prefills take the suffix start as a dynamic
         # operand (prefix-cached admissions prefill only tokens[shared:]);
         # plain-allocator pools (hybrid/MoE) always start at 0
         paged_sfx = self._paged_suffix
         if self.prefill_buckets and paged_sfx:
-            self._prefill = jax.jit(
-                lambda p, t, c, i, n, s0: self.model.prefill_slot(
-                    p, t, self.ctx, c, i, true_len=n, start_pos=s0))
+            def serve_prefill(p, t, c, i, n, s0):
+                return model.prefill_slot(p, t, self.ctx, c, i, true_len=n,
+                                          start_pos=s0)
         elif self.prefill_buckets:
-            self._prefill = jax.jit(
-                lambda p, t, c, i, n: self.model.prefill_slot(
-                    p, t, self.ctx, c, i, true_len=n))
+            def serve_prefill(p, t, c, i, n):
+                return model.prefill_slot(p, t, self.ctx, c, i, true_len=n)
         elif paged_sfx:
-            self._prefill = jax.jit(
-                lambda p, t, c, i, s0: self.model.prefill_slot(
-                    p, t, self.ctx, c, i, start_pos=s0))
+            def serve_prefill(p, t, c, i, s0):
+                return model.prefill_slot(p, t, self.ctx, c, i, start_pos=s0)
         else:
             # one dispatch per admission; recompiles per distinct prompt
             # length (prefill shapes)
-            self._prefill = jax.jit(
-                lambda p, t, c, i: self.model.prefill_slot(
-                    p, t, self.ctx, c, i))
+            def serve_prefill(p, t, c, i):
+                return model.prefill_slot(p, t, self.ctx, c, i)
+        self._prefill = jax.jit(serve_prefill)
         # chunked prefill always rides true_len (the final partial chunk
         # pads up to the budget) + start_pos (the chunk cursor) — ONE
         # compiled prefill executable for the whole engine
         if getattr(self, "scheduler", None) is not None:
-            self._chunk_prefill = jax.jit(
-                lambda p, t, c, i, n, s0: self.model.prefill_slot(
-                    p, t, self.ctx, c, i, true_len=n, start_pos=s0))
+            def serve_chunk_prefill(p, t, c, i, n, s0):
+                return model.prefill_slot(p, t, self.ctx, c, i, true_len=n,
+                                          start_pos=s0)
+
+            self._chunk_prefill = jax.jit(serve_chunk_prefill)
 
     # ------------------------------------------------------------------
     # paged-pool device helpers
@@ -1468,7 +1474,8 @@ class ServeEngine:
                 logits, self.cache = self._prefill(
                     self.params, tokens, self.cache, jnp.int32(i))
         self.lengths[i] = p_len
-        req._next = int(jnp.argmax(logits[0]))
+        with span("sync.first_token"):
+            req._next = int(jnp.argmax(logits[0]))
         self.prefill_dispatches += 1
         self.admissions += 1
         # per-step prefill-token ledger: without the chunk scheduler a
@@ -1516,38 +1523,40 @@ class ServeEngine:
         req, i = job.req, job.slot
         start = job.cursor
         n_real = min(self.scheduler.chunk, job.p_len - start)
-        # never let start + chunk cross max_len: dynamic_update_slice
-        # CLAMPS out-of-range starts, which would silently shift rows
-        pad_to = min(self.scheduler.chunk, self.max_len - start)
-        toks = np.asarray(req.prompt, np.int32)[start:start + n_real]
-        if pad_to > n_real:
-            toks = np.pad(toks, (0, pad_to - n_real))
-        if len(toks) in self._prefill_lens:
-            self.prefill_cache_hits += 1
-        else:
-            self._prefill_lens.add(len(toks))
-            self.prefill_compiles += 1
-        try:
-            self._with_retries("prefill", None, uid=req.uid)
-        except InjectedFault as e:
-            reason = REASON_RETRIES if e.transient else REASON_INJECTED
-            self._finish_request(i, RequestState.FAILED, reason, error=e)
-            return
-        tokens = jnp.asarray(toks[None, :])
-        try:
-            with self._mesh_ctx():
-                logits, self.cache = self._chunk_prefill(
-                    self.params, tokens, self.cache, jnp.int32(i),
-                    jnp.int32(n_real), jnp.int32(start))
-        except Exception as e:
-            self._finish_request(i, RequestState.FAILED,
-                                 REASON_PREFILL_ERROR, error=e)
-            raise
+        with span("chunk_prefill", start=start, tokens=n_real):
+            # never let start + chunk cross max_len: dynamic_update_slice
+            # CLAMPS out-of-range starts, which would silently shift rows
+            pad_to = min(self.scheduler.chunk, self.max_len - start)
+            toks = np.asarray(req.prompt, np.int32)[start:start + n_real]
+            if pad_to > n_real:
+                toks = np.pad(toks, (0, pad_to - n_real))
+            if len(toks) in self._prefill_lens:
+                self.prefill_cache_hits += 1
+            else:
+                self._prefill_lens.add(len(toks))
+                self.prefill_compiles += 1
+            try:
+                self._with_retries("prefill", None, uid=req.uid)
+            except InjectedFault as e:
+                reason = REASON_RETRIES if e.transient else REASON_INJECTED
+                self._finish_request(i, RequestState.FAILED, reason, error=e)
+                return
+            tokens = jnp.asarray(toks[None, :])
+            try:
+                with self._mesh_ctx():
+                    logits, self.cache = self._chunk_prefill(
+                        self.params, tokens, self.cache, jnp.int32(i),
+                        jnp.int32(n_real), jnp.int32(start))
+            except Exception as e:
+                self._finish_request(i, RequestState.FAILED,
+                                     REASON_PREFILL_ERROR, error=e)
+                raise
         self.prefill_dispatches += 1
         self._step_prefill_tokens += n_real
         if self.scheduler.advance(job, n_real):
             self.lengths[i] = job.p_len
-            req._next = int(jnp.argmax(logits[0]))
+            with span("sync.first_token"):
+                req._next = int(jnp.argmax(logits[0]))
             if self.kv_pool is not None:
                 # pages are final now — register them for prefix hits
                 # (deferred from _try_admit; no-op for plain allocators)
@@ -1561,29 +1570,19 @@ class ServeEngine:
 
     def _note_step(self, decode_rows: int):
         """End-of-step bookkeeping: the prefill-token ledger (counters +
-        scheduler step_log) and the live metrics gauges.  The ledger
-        resets HERE, not at step start: direct ``add_request`` calls
-        between steps prefill outside ``step()`` and their tokens belong
-        to the step whose decode they delayed (the next one)."""
+        scheduler step_log).  The ledger resets HERE, not at step start:
+        direct ``add_request`` calls between steps prefill outside
+        ``step()`` and their tokens belong to the step whose decode they
+        delayed (the next one).  Gauges (queue depth, active slots, pool
+        occupancy) are computed at scrape time by ``metrics_report``."""
         spent = self._step_prefill_tokens
         self._step_prefill_tokens = 0
         self.max_prefill_tokens_per_step = max(
             self.max_prefill_tokens_per_step, spent)
         if spent:
             self.counters["prefill_tokens"] += spent
-        self.counters["max_prefill_tokens_per_step"] = \
-            self.max_prefill_tokens_per_step
         if self.scheduler is not None:
             self.scheduler.note_step(spent, decode_rows)
-        m = self.metrics
-        m.set_gauge("queue_depth", len(self.queue))
-        m.set_gauge("active_slots", float(
-            sum(s is not None and not s.done for s in self.slots)))
-        if self.kv_pool is not None:
-            st = self.kv_pool.stats()
-            for key in ("pages_active", "prefix_hit_tokens"):
-                if key in st:
-                    m.set_gauge(f"kv_pool.{key}", st[key])
 
     def metrics_report(self) -> dict:
         """One JSON-able observability snapshot: lifecycle counters
@@ -1594,8 +1593,7 @@ class ServeEngine:
         snap = self.metrics.snapshot()
         counters = dict(self.counters)
         counters.update(snap["counters"])
-        gauges = dict(snap["gauges"])
-        gauges.update({
+        gauges = {
             "queue_depth": float(len(self.queue)),
             "active_slots": float(
                 sum(s is not None and not s.done for s in self.slots)),
@@ -1604,8 +1602,8 @@ class ServeEngine:
             "prefill_cache_hits": float(self.prefill_cache_hits),
             "max_prefill_tokens_per_step":
                 float(self.max_prefill_tokens_per_step),
-        })
-        gauges["draining"] = float(self.draining)
+            "draining": float(self.draining),
+        }
         report = {"counters": counters, "gauges": gauges,
                   "histograms": snap["histograms"]}
         if self.kv_pool is not None:
@@ -1634,7 +1632,14 @@ class ServeEngine:
         quarantines ITS slot only: the victim lands FAILED(nan_logits)
         with no token emitted and the survivors' streams are untouched
         (decode is row-independent, so they stay bitwise-identical to a
-        fault-free run under W4A16)."""
+        fault-free run under W4A16).
+
+        Each phase runs inside a ``serve.*`` host span (``span``), so a
+        profiler trace names what the host did in each device idle gap."""
+        with span("step"):
+            return self._step()
+
+    def _step(self) -> list[tuple[int, int]]:
         t0 = self.clock()
         # the process_crash boundary fires BEFORE any state mutation: a
         # "crash between steps" leaves exactly the journal the previous
@@ -1642,43 +1647,47 @@ class ServeEngine:
         act = self._fire("process_crash", scoped=False)
         if act is not None and act.error is not None:
             raise act.error
-        self._expire_deadlines()
-        self._pump()
+        with span("admit"):
+            self._expire_deadlines()
+            self._pump()
         if self.scheduler is not None:
             self._sched_run_chunk()
-        toks = np.zeros((self.batch_size,), np.int32)
-        out = []
-        active = []
-        n_live = sum(r is not None for r in self.slots)
-        self.max_concurrent = max(self.max_concurrent, n_live)
-        for i, req in enumerate(self.slots):
-            if req is None or req.done:
-                continue
-            if req.state is RequestState.PREFILLING:
-                # a chunked admission mid-prefill holds its slot but does
-                # not decode; the batched dispatch's scatter for this lane
-                # writes a junk row at lengths[i] (= the chunk cursor),
-                # which the NEXT chunk overwrites before it is ever read
-                continue
-            if not req.generated:
-                if req._next is None:
-                    raise RuntimeError(
-                        f"request {req.uid} occupies slot {i} but was never "
-                        "prefilled (requests enter the batch via "
-                        "add_request, which runs the admission prefill)")
-                req.first_token_at = self.clock()
-                req._last_token_at = req.first_token_at
-                self.metrics.observe("ttft_ms", req.ttft_ms())
-                req.generated.append(req._next)
-                out.append((req.uid, req._next))
-                self._journal_append({"t": "token", "uid": req.uid,
-                                      "tok": int(req._next)})
-                if len(req.generated) >= req.max_new_tokens:
-                    self._finish_request(i, RequestState.FINISHED,
-                                         REASON_MAX_NEW)
+        with span("emit"):   # fresh prefills' first tokens; decode rows
+            toks = np.zeros((self.batch_size,), np.int32)
+            out = []
+            active = []
+            n_live = sum(r is not None for r in self.slots)
+            self.max_concurrent = max(self.max_concurrent, n_live)
+            for i, req in enumerate(self.slots):
+                if req is None or req.done:
                     continue
-            toks[i] = req.generated[-1]
-            active.append(i)
+                if req.state is RequestState.PREFILLING:
+                    # a chunked admission mid-prefill holds its slot but
+                    # does not decode; the batched dispatch's scatter for
+                    # this lane writes a junk row at lengths[i] (= the chunk
+                    # cursor), which the NEXT chunk overwrites before it is
+                    # ever read
+                    continue
+                if not req.generated:
+                    if req._next is None:
+                        raise RuntimeError(
+                            f"request {req.uid} occupies slot {i} but was "
+                            "never prefilled (requests enter the batch via "
+                            "add_request, which runs the admission "
+                            "prefill)")
+                    req.first_token_at = self.clock()
+                    req._last_token_at = req.first_token_at
+                    self.metrics.observe("ttft_ms", req.ttft_ms())
+                    req.generated.append(req._next)
+                    out.append((req.uid, req._next))
+                    self._journal_append({"t": "token", "uid": req.uid,
+                                          "tok": int(req._next)})
+                    if len(req.generated) >= req.max_new_tokens:
+                        self._finish_request(i, RequestState.FINISHED,
+                                             REASON_MAX_NEW)
+                        continue
+                toks[i] = req.generated[-1]
+                active.append(i)
         if not active:
             self._note_step(0)
             self._finish_step(t0)
@@ -1686,30 +1695,32 @@ class ServeEngine:
         logits = self._guarded_decode(toks, active)
         # one vectorized argmax + host transfer per step, not one per
         # slot; the finiteness reduction rides the same device round-trip
-        next_toks = np.asarray(jnp.argmax(logits, axis=-1))
-        nan_rows = np.asarray(jnp.any(~jnp.isfinite(logits), axis=-1))
-        now = self.clock()
-        for i in active:
-            req = self.slots[i]
-            if req is None or req.done:
-                continue               # quarantined by a mid-step fault
-            if req.uid in self._step_poison or bool(nan_rows[i]):
-                self._finish_request(i, RequestState.FAILED,
-                                     REASON_NAN_LOGITS)
-                continue
-            tok = int(next_toks[i])
-            req.generated.append(tok)
-            self.lengths[i] += 1
-            out.append((req.uid, tok))
-            self._journal_append({"t": "token", "uid": req.uid,
-                                  "tok": tok})
-            if req._last_token_at is not None:
-                self.metrics.observe("itl_ms",
-                                     (now - req._last_token_at) * 1e3)
-            req._last_token_at = now
-            if len(req.generated) >= req.max_new_tokens:
-                self._finish_request(i, RequestState.FINISHED,
-                                     REASON_MAX_NEW)
+        with span("sync.tokens"):
+            next_toks = np.asarray(jnp.argmax(logits, axis=-1))
+            nan_rows = np.asarray(jnp.any(~jnp.isfinite(logits), axis=-1))
+        with span("emit"):
+            now = self.clock()
+            for i in active:
+                req = self.slots[i]
+                if req is None or req.done:
+                    continue               # quarantined by a mid-step fault
+                if req.uid in self._step_poison or bool(nan_rows[i]):
+                    self._finish_request(i, RequestState.FAILED,
+                                         REASON_NAN_LOGITS)
+                    continue
+                tok = int(next_toks[i])
+                req.generated.append(tok)
+                self.lengths[i] += 1
+                out.append((req.uid, tok))
+                self._journal_append({"t": "token", "uid": req.uid,
+                                      "tok": tok})
+                if req._last_token_at is not None:
+                    self.metrics.observe("itl_ms",
+                                         (now - req._last_token_at) * 1e3)
+                req._last_token_at = now
+                if len(req.generated) >= req.max_new_tokens:
+                    self._finish_request(i, RequestState.FINISHED,
+                                         REASON_MAX_NEW)
         self._note_step(len(active))
         self._finish_step(t0)
         return out
@@ -1719,18 +1730,19 @@ class ServeEngine:
         commits the step's token records (the ``journal_sync='batch'``
         fsync point), then the watchdog hears the step's heartbeat and
         its verdicts run the degradation ladder."""
-        self._journal_flush()
-        if self.watchdog is None:
-            return
-        verdict = self.watchdog.beat((self.clock() - t0) * 1e3)
-        if verdict == "degrade":
-            # first strikes ride the existing bitwise-preserving ladder
-            # when a rung is armed; otherwise the strike just counts
-            if self.act_quant == "mixfp4":
-                self._degrade_fused()
-            self.counters["watchdog_degrades"] += 1
-        elif verdict == "fail":
-            self._watchdog_fail()
+        with span("journal"):
+            self._journal_flush()
+            if self.watchdog is None:
+                return
+            verdict = self.watchdog.beat((self.clock() - t0) * 1e3)
+            if verdict == "degrade":
+                # first strikes ride the existing bitwise-preserving ladder
+                # when a rung is armed; otherwise the strike just counts
+                if self.act_quant == "mixfp4":
+                    self._degrade_fused()
+                self.counters["watchdog_degrades"] += 1
+            elif verdict == "fail":
+                self._watchdog_fail()
 
     def _watchdog_fail(self):
         """Past the degradation rung: fail the most starved in-flight
@@ -1757,7 +1769,16 @@ class ServeEngine:
         'dispatch' faults trigger the fused -> 2-pass degradation (or a
         backoff retry off the fused path), transients back off and retry,
         and a fatal fault quarantines its victim's slot, then decodes the
-        survivors."""
+        survivors.  Its span records the rows decoded, the cache rows the
+        occupied slots hold, and the pool pages reserved for them."""
+        pool = {} if self.kv_pool is None else {
+            "pages": self.kv_pool.pages_active}
+        held = sum(int(self.lengths[i]) for i, r in enumerate(self.slots)
+                   if r is not None)
+        with span("decode", rows=len(active), held_tokens=held, **pool):
+            return self._decode_with_faults(toks, active)
+
+    def _decode_with_faults(self, toks, active):
         self._step_poison = set()
         attempt = 0
         spins = 0

@@ -3,14 +3,20 @@
 The engine already keeps typed lifecycle counters (``engine.counters``) and
 ad-hoc reports (``pool_report``, ``robustness_report``).  This module is the
 uniform observability layer on top: a small registry the engine feeds every
-``step()`` — queue depth, active slots, KV-pool occupancy / prefix-hit rate,
-TTFT and inter-token-latency samples — exposed two ways:
+``step()`` with TTFT and inter-token-latency samples, exposed two ways:
 
 * ``engine.metrics_report()`` — one JSON-able dict (counters + gauges +
-  histogram percentile snapshots + scheduler ledger), consumed by the
+  histogram percentile snapshots + scheduler ledger; queue depth, active
+  slots and KV-pool occupancy are read at scrape time), consumed by the
   frontend benchmark and the tests;
 * ``render_prometheus(report)`` — Prometheus text exposition for the HTTP
   server's ``GET /metrics`` (serving.server).
+
+``span(name, **args)`` marks a phase of the engine's step on the
+profiler's own clock (``jax.profiler``): host spans named ``serve.<name>``
+land in the same trace as the device's programs, so each idle gap on the
+device is named by what the host was doing in it.  Without a running
+profiler a span costs about a microsecond.
 
 Everything here is host-side pure Python with no locking requirements
 beyond the GIL: the engine worker thread is the only writer, and readers
@@ -26,7 +32,17 @@ from __future__ import annotations
 import collections
 from typing import Iterable
 
-__all__ = ["MetricsRegistry", "Histogram", "render_prometheus", "percentile"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["MetricsRegistry", "Histogram", "render_prometheus", "percentile",
+           "span"]
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """A host span ``serve.<name>`` in the profiler trace.  ``args`` are
+    recorded with it when it opens; pass only host values already at hand
+    (reading a device value here would add a sync to the step)."""
+    return TraceAnnotation("serve." + name, **args)
 
 
 def percentile(samples: Iterable[float], q: float) -> float:
@@ -70,22 +86,19 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Name -> counter/gauge/histogram.  Names are dotted lowercase
+    """Name -> counter/histogram.  Names are dotted lowercase
     (``requests.finished``, ``ttft_ms``); the Prometheus renderer
     sanitizes them.  Creation is implicit on first touch so call sites
-    stay one-liners."""
+    stay one-liners.  Gauges are read at scrape time
+    (``engine.metrics_report``), not stored here."""
 
     def __init__(self, histogram_window: int = 4096):
         self.counters: collections.Counter[str] = collections.Counter()
-        self.gauges: dict[str, float] = {}
         self.histograms: dict[str, Histogram] = {}
         self._hist_window = histogram_window
 
     def inc(self, name: str, value: float = 1.0) -> None:
         self.counters[name] += value
-
-    def set_gauge(self, name: str, value: float) -> None:
-        self.gauges[name] = float(value)
 
     def observe(self, name: str, value: float) -> None:
         hist = self.histograms.get(name)
@@ -96,7 +109,6 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         return {
             "counters": dict(self.counters),
-            "gauges": dict(self.gauges),
             "histograms": {k: h.snapshot()
                            for k, h in self.histograms.items()},
         }

@@ -78,10 +78,15 @@ class ChunkedPrefillScheduler:
     work past the budget.
 
     ``step_log`` records ``{"prefill_tokens", "decode_rows", "backlog"}``
-    per engine step — the deterministic, wall-clock-free evidence that no
-    decode step was delayed by more than ``chunk_tokens`` (the fairness
-    test and the frontend benchmark's stall-free assertion both read it).
+    for the latest ``LOG_STEPS`` engine steps — the deterministic,
+    wall-clock-free evidence that no decode step was delayed by more than
+    ``chunk_tokens`` (the fairness test and the frontend benchmark's
+    stall-free assertion both read it).  The largest prefill spend over
+    the whole life of the scheduler is kept apart, so a long-lived server
+    neither grows the log nor scans it at every scrape.
     """
+
+    LOG_STEPS = 4096        # like the metrics histograms' sample window
 
     def __init__(self, chunk_tokens: int):
         if chunk_tokens < 1:
@@ -90,7 +95,9 @@ class ChunkedPrefillScheduler:
         self.chunk = int(chunk_tokens)
         self._jobs: collections.OrderedDict[int, PrefillJob] = \
             collections.OrderedDict()
-        self.step_log: list[dict] = []
+        self.step_log: collections.deque[dict] = collections.deque(
+            maxlen=self.LOG_STEPS)
+        self._max_prefill_tokens = 0
         self.chunks_run = 0
         self.tokens_prefilled = 0
         self.jobs_completed = 0
@@ -143,6 +150,8 @@ class ChunkedPrefillScheduler:
 
     # -- per-step ledger ---------------------------------------------------
     def note_step(self, prefill_tokens: int, decode_rows: int) -> None:
+        self._max_prefill_tokens = max(self._max_prefill_tokens,
+                                       int(prefill_tokens))
         self.step_log.append({
             "prefill_tokens": int(prefill_tokens),
             "decode_rows": int(decode_rows),
@@ -157,7 +166,7 @@ class ChunkedPrefillScheduler:
         return len(self._jobs)
 
     def max_prefill_tokens_per_step(self) -> int:
-        return max((s["prefill_tokens"] for s in self.step_log), default=0)
+        return self._max_prefill_tokens
 
     def jobs_report(self) -> list[dict]:
         """Per-job cursor snapshot (FIFO order) — the drain ledger

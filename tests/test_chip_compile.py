@@ -4,15 +4,18 @@ The TPU compiler is installed with JAX; it compiles for a described
 ``v5e:2x2`` topology, so these tests refuse what interpret mode cannot
 see: tiles off the (8, 128) tiling, casts and shape casts the chip has no
 lowering for, scalars in vector memory, and kernels over the VMEM limit.
-Every compile is at gemma2-2b's published widths and must hold a native
-kernel (``tpu_custom_call``).  Nothing runs: results are checked by the
-interpret-mode tests and on the chip by ``chip_smoke.py``.
+Every compile is at gemma2-2b's published widths and must hold each
+native kernel (``tpu_custom_call``) under its public name: a profiler
+trace shows a kernel by that name, and the benchmark finds the kernels it
+measures by it, so a rename fails here.  Nothing runs: results are checked
+by the interpret-mode tests and on the chip by ``chip_smoke.py``.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and the test workers all
 import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,15 +63,20 @@ def _weight(sharding, k, n):
                    (k, n), "float32")
 
 
-def _compile_native(f, *args):
+def _compile_native(kernels, f, *args):
+    """Compile ``f`` for the described chip; its text must hold a native
+    kernel named after each of ``kernels``."""
     text = jax.jit(f).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    for name in [kernels] if isinstance(kernels, str) else kernels:
+        assert re.search(rf'%{name}(\.\d+)? = [^\n]*custom_call_target='
+                         r'"tpu_custom_call"', text), name
 
 
 @pytest.mark.parametrize("m", [DECODE_M, PREFILL_M])
 @pytest.mark.parametrize("k,n", PROJECTIONS + [(D_MODEL, VOCAB)])
 def test_w4a16_gemm_compiles(one_chip, m, k, n):
-    _compile_native(lambda x, w: qtensor.qmm(x, w, interpret=False),
+    _compile_native("mixfp4_gemm_w4a16",
+                    lambda x, w: qtensor.qmm(x, w, interpret=False),
                     _sds(one_chip, (m, k), jnp.bfloat16),
                     _weight(one_chip, k, n))
 
@@ -77,6 +85,7 @@ def test_w4a16_gemm_compiles(one_chip, m, k, n):
 @pytest.mark.parametrize("k,n", PROJECTIONS)
 def test_fused_w4a4_gemm_compiles(one_chip, m, k, n):
     _compile_native(
+        "mixfp4_gemm_w4a4_fused",
         lambda x, w: qtensor.qmm(x, w, interpret=False, fuse_act_quant=True,
                                  per_row_act=True),
         _sds(one_chip, (m, k), jnp.bfloat16), _weight(one_chip, k, n))
@@ -88,6 +97,7 @@ def test_fused_w4a4_variants_compile(one_chip, per_row):
     k, n = D_MODEL, D_FF
     signs = _sds(one_chip, (k,)) if per_row else None
     _compile_native(
+        "mixfp4_gemm_w4a4_fused",
         lambda x, w, s: qtensor.qmm(x, w, interpret=False,
                                     fuse_act_quant=True,
                                     per_row_act=per_row, act_rht_signs=s),
@@ -99,6 +109,7 @@ def test_fused_w4a4_variants_compile(one_chip, per_row):
 def test_two_pass_w4a4_gemm_compiles(one_chip, m):
     k, n = D_FF, D_MODEL
     _compile_native(
+        ("mixfp4_quant_rows", "mixfp4_gemm_w4a4"),
         lambda x, w: qtensor.qmm(
             qtensor.quantize_rows(x, pad_to=k, per_row=True,
                                   interpret=False), w, interpret=False),
@@ -112,6 +123,7 @@ def test_two_pass_w4a4_gemm_compiles(one_chip, m):
 ])
 def test_quant_rows_compiles(one_chip, m, k, kw):
     _compile_native(
+        "mixfp4_quant_rows",
         lambda x: mixfp4_quant.mixfp4_quant_rows(x, interpret=False, **kw),
         _sds(one_chip, (m, k)))
 
@@ -119,7 +131,7 @@ def test_quant_rows_compiles(one_chip, m, k, kw):
 @pytest.mark.parametrize("m", [DECODE_M, 136])
 def test_fwht_rows_compiles(one_chip, m):
     _compile_native(
-        lambda x, s: fwht.fwht_rows(x, s, interpret=False),
+        "fwht_rows", lambda x, s: fwht.fwht_rows(x, s, interpret=False),
         _sds(one_chip, (m, D_MODEL)), _sds(one_chip, (D_MODEL,)))
 
 
@@ -131,6 +143,7 @@ def _kv(sharding, lead):
 def test_attention_fixed_slot_compiles(one_chip):
     b, s = 4, 4096
     _compile_native(
+        "mixfp4_attn_decode",
         lambda q, kp, ks, vp, vs, ln, w: mixfp4_attn.mixfp4_attn_decode(
             q, kp, ks, vp, vs, ln, window=w, softcap=50.0, interpret=False),
         _sds(one_chip, (b, H, DH), jnp.bfloat16), *_kv(one_chip, (b, s)),
@@ -141,6 +154,7 @@ def test_attention_paged_compiles(one_chip):
     b, s, page = 4, 4096, 16
     pages = 1 + b * s // page
     _compile_native(
+        "mixfp4_attn_decode_paged",
         lambda q, kp, ks, vp, vs, bt, ln, w:
         mixfp4_attn.mixfp4_attn_decode_paged(
             q, kp, ks, vp, vs, bt, ln, window=w, softcap=50.0,

@@ -89,6 +89,22 @@ def test_scheduler_step_ledger():
     assert s.max_prefill_tokens_per_step() == 4
 
 
+def test_scheduler_step_ledger_is_bounded():
+    """The log keeps the latest LOG_STEPS steps; the largest prefill spend
+    is remembered after its step has left the log."""
+    s = ChunkedPrefillScheduler(4)
+    n = s.LOG_STEPS
+    s.note_step(3, 1)
+    for k in range(n + 10):
+        s.note_step(k % 3, 1)
+    assert len(s.step_log) == n
+    assert s.step_log[-1]["prefill_tokens"] == (n + 9) % 3
+    assert max(e["prefill_tokens"] for e in s.step_log) == 2
+    assert s.max_prefill_tokens_per_step() == 3
+    assert s.report()["max_prefill_tokens_per_step"] == 3
+    assert s.report()["steps_logged"] == n
+
+
 def test_scheduler_validation():
     with pytest.raises(ValueError, match="chunk budget"):
         ChunkedPrefillScheduler(0)

@@ -21,6 +21,7 @@ from repro.core.qgemm import QuantConfig
 from repro.models.base import ArchConfig, build_model
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.faults import FaultInjector, FaultRule
+from repro.serving.metrics import render_prometheus
 from repro.serving.server import (ServingServer, get_json, resume_stream,
                                   scrape_metrics, stream_generate)
 
@@ -304,6 +305,29 @@ def test_readyz_reports_pool_gauges(small_cfg, params):
     assert body["pool"]["pages_total"] > 0
     assert body["pool"]["pages_free"] == body["pool"]["pages_total"]
     assert body["pool"]["pages_active"] == 0
+
+
+def test_metrics_text_has_one_family_per_name(small_cfg, params):
+    """A paged, chunked engine mid-flight: every metric family is declared
+    once (a repeated ``# TYPE`` line is invalid exposition), and the
+    queue and pool gauges are still there, once each."""
+    eng = _engine(small_cfg, params, kv_quant="mixfp4", kv_pool=9,
+                  kv_page_len=16, prefill_chunk=4)
+    for uid, prompt in enumerate([[5, 4, 3, 2, 1, 7], [9, 8, 7]]):
+        eng.submit(Request(uid=uid, prompt=np.asarray(prompt, np.int32),
+                           max_new_tokens=6))
+    for _ in range(4):
+        eng.step()
+    text = render_prometheus(eng.metrics_report())
+    types = [ln.split()[2] for ln in text.splitlines()
+             if ln.startswith("# TYPE ")]
+    assert len(types) == len(set(types)), sorted(
+        t for t in set(types) if types.count(t) > 1)
+    for family in ("mixfp4_queue_depth", "mixfp4_kv_pool_pages_active"):
+        assert types.count(family) == 1
+        assert len([ln for ln in text.splitlines()
+                    if ln.startswith(family + " ")]) == 1
+    assert "mixfp4_kv_pool_pages_active 2" in text
 
 
 def test_resume_replays_finished_stream_bitwise(small_cfg, params):
