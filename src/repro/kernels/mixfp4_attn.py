@@ -34,15 +34,26 @@ lengths, sliding windows and the S padding the ``ops`` entry may add;
 ``softcap`` is a compile-time constant (it is an arch property, not a
 per-layer one).
 
+Each row visits only the key blocks that hold a key it attends to
+(``key_block_range``: from the window's first row to the row before the
+length).  The grid's block index is clamped into that range before the
+K/V blocks are named (in the fixed-slot kernel's index maps; in the
+paged kernel's unit table, ``_visited_units``), so a step outside it
+names its neighbour's block and the pipeline starts no DMA for it, and
+the body skips the decode and the flash update there.  The skip is
+exact: a wholly masked block leaves the running state bit for bit as it
+was (``alpha = exp(0) = 1``, ``p = 0``).
+
 ``mixfp4_attn_decode_paged`` is the same flash loop over a *paged* pool
 (``serving/kvpool.py``): K/V slabs are (P, page_len, Hkv, ...) physical
 pages and a per-sequence block table maps logical key-block j to physical
-pages via ``pltpu.PrefetchScalarGridSpec`` scalar prefetch — the block
-table is read at *index-map* time, so each grid step DMAs exactly the
-pages it needs and the kernel body never sees the indirection.  Both
-kernels share ``_flash_step``, so a paged read over the same logical rows
-runs literally the same arithmetic as the fixed-slot kernel (the bitwise
-paged==fixed contract the serving tests pin).
+pages via ``pltpu.PrefetchScalarGridSpec`` scalar prefetch — the table
+of DMA units built from it is read at *index-map* time, so each grid
+step DMAs exactly the pages it needs and the kernel body never sees the
+indirection.  Both kernels share ``_flash_step``, so a paged read over
+the same logical rows runs literally the same arithmetic as the
+fixed-slot kernel (the bitwise paged==fixed contract the serving tests
+pin).
 """
 from __future__ import annotations
 
@@ -50,13 +61,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import lanes
 from repro.kernels.mixfp4_gemm import _decode_nibbles, _expand_scales
 
-__all__ = ["mixfp4_attn_decode", "mixfp4_attn_decode_paged"]
+__all__ = ["mixfp4_attn_decode", "mixfp4_attn_decode_paged",
+           "key_block_range", "paged_key_block", "paged_kv_blocks"]
 
 _G = 16
 _NEG_INF = -1e30
@@ -85,13 +98,45 @@ def _dot_f32_bf16(a, b, dims):
     return dot(hi, b, dims) + dot(lo, b, dims)
 
 
-def _flash_step(qe_ref, qo_ref, kp, ks, vp, vs, kv_len, win, s32_ref,
+def key_block_range(kv_len, win, bs: int, n_blocks: int, xp=jnp):
+    """First and last of ``n_blocks`` key blocks of ``bs`` rows that hold a
+    key ``_flash_step``'s mask keeps for a query at row ``kv_len - 1``
+    under window ``win`` (0 = full causal): rows ``max(0, kv_len - win)``
+    to ``kv_len - 1``.  ``(0, 0)`` when ``kv_len <= 0``.  The one formula
+    for the kernels (the fixed-slot index maps, the paged unit table, the
+    body; traced int32, ``xp=jnp``) and the engine's host count of blocks
+    visited (numpy, ``xp=np``)."""
+    last = xp.clip((kv_len - 1) // bs, 0, n_blocks - 1)
+    first = xp.where(win > 0, (kv_len - win) // bs, 0)
+    return xp.clip(first, 0, last), last
+
+
+def _visited_units(block_tables, lengths, win, bs: int, n_blocks: int,
+                   n_sub: int, upp: int):
+    """(B, n_blocks * n_sub) int32: the physical DMA unit (a page when
+    ``upp`` is 1, else one of a page's ``upp`` slices) that sub-block t of
+    grid step (i, j) reads, at column ``j * n_sub + t``.  j is clamped
+    into row i's ``key_block_range`` first, so a step outside the range
+    names its neighbour's units and the pipeline starts no DMA for it.
+    One small XLA gather per call: clamping inside each of the paged
+    kernel's ``4 * n_sub`` index maps costs tens of scalar operations per
+    map at every grid step."""
+    lo, hi = key_block_range(lengths[:, None], win, bs, n_blocks)
+    j = jnp.clip(jnp.arange(n_blocks)[None, :], lo, hi)
+    u = (j[:, :, None] * n_sub + jnp.arange(n_sub)).reshape(j.shape[0], -1)
+    pages = jnp.take_along_axis(block_tables, u // upp, axis=1)
+    return pages * upp + u % upp
+
+
+def _flash_step(qe_ref, qo_ref, load_kv, kv_len, win, s32_ref,
                 oe_ref, oo_ref, acce_ref, acco_ref, m_ref, l_ref, *,
-                softcap: float, dh: int):
+                softcap: float, dh: int, bs: int):
     """One key-block step of the flash-decoding loop, shared verbatim by
     the fixed-slot and paged kernels: decode the packed (bs, Hkv*dh//2)
     K/V block, fold it into the running (max, sum, acc) scratch state, and
-    emit the normalized output rows on the last block.  Keeping both
+    emit the normalized output rows on the last block.  ``load_kv()``
+    gives the block's packed (kp, ks, vp, vs); it is called, and the block
+    folded in, only inside the row's ``key_block_range``.  Keeping both
     kernels on this one body is what makes paged==fixed a *bitwise*
     contract rather than an allclose one.
 
@@ -110,34 +155,38 @@ def _flash_step(qe_ref, qo_ref, kp, ks, vp, vs, kv_len, win, s32_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    bs = kp.shape[0]
-    dot = _dot_f32_bf16
-    nt = (((1,), (1,)), ((), ()))
-    nn = (((1,), (0,)), ((), ()))
-    # the per-tensor scales multiply the scores and the emitted rows
-    ke, ko = _decode_kv_block(kp, ks)                          # (bs, W)
-    s = (dot(qe_ref[0], ke, nt) + dot(qo_ref[0], ko, nt)
-         ) * (s32_ref[0] * dh ** -0.5)                         # (H, bs)
-    if softcap:
-        s = softcap * jnp.tanh(s * (1.0 / softcap))
+    lo, hi = key_block_range(kv_len, win, bs, ns)
 
-    # decode-position masking: the query sits at position kv_len - 1
-    kpos = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    mask = kpos < kv_len
-    mask &= (kpos > kv_len - 1 - win) | (win <= 0)
-    s = jnp.where(mask, s, _NEG_INF)
+    @pl.when((lo <= s_idx) & (s_idx <= hi))
+    def _fold():
+        dot = _dot_f32_bf16
+        nt = (((1,), (1,)), ((), ()))
+        nn = (((1,), (0,)), ((), ()))
+        kp, ks, vp, vs = load_kv()
+        # the per-tensor scales multiply the scores and the emitted rows
+        ke, ko = _decode_kv_block(kp, ks)                      # (bs, W)
+        s = (dot(qe_ref[0], ke, nt) + dot(qo_ref[0], ko, nt)
+             ) * (s32_ref[0] * dh ** -0.5)                     # (H, bs)
+        if softcap:
+            s = softcap * jnp.tanh(s * (1.0 / softcap))
 
-    # online-softmax update (flash-decoding running state in scratch)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[...] = m_new
+        # decode-position masking: the query sits at position kv_len - 1
+        kpos = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        mask = kpos < kv_len
+        mask &= (kpos > kv_len - 1 - win) | (win <= 0)
+        s = jnp.where(mask, s, _NEG_INF)
 
-    ve, vo = _decode_kv_block(vp, vs)
-    acce_ref[...] = acce_ref[...] * alpha + dot(p, ve, nn)
-    acco_ref[...] = acco_ref[...] * alpha + dot(p, vo, nn)
+        # online-softmax update (flash-decoding running state in scratch)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+
+        ve, vo = _decode_kv_block(vp, vs)
+        acce_ref[...] = acce_ref[...] * alpha + dot(p, ve, nn)
+        acco_ref[...] = acco_ref[...] * alpha + dot(p, vo, nn)
 
     @pl.when(s_idx == ns - 1)
     def _emit():
@@ -187,7 +236,7 @@ def _call(name, kernel, grid, n_prefetch, kv_specs, q_pair, h, w, interpret,
           scalars, kv_args):
     """The pallas_call both kernels share, named ``name`` (the public
     kernel's name, which a profiler trace shows).  ``scalars`` is the
-    ``n_prefetch`` scalar-prefetch operands (block table, lengths, window)
+    ``n_prefetch`` scalar-prefetch operands (unit table, lengths, window)
     then the two K/V scales, which go to SMEM; then come the block-
     diagonal query halves and the packed K/V operands.  Outputs are the
     even/odd halves, with the flash state in VMEM scratch."""
@@ -215,33 +264,33 @@ def _call(name, kernel, grid, n_prefetch, kv_specs, q_pair, h, w, interpret,
 
 def _attn_decode_kernel(len_ref, win_ref, s32_ref, qe_ref, qo_ref,
                         kp_ref, ks_ref, vp_ref, vs_ref, *refs,
-                        softcap: float, dh: int):
-    _flash_step(qe_ref, qo_ref, kp_ref[0], ks_ref[0], vp_ref[0], vs_ref[0],
+                        softcap: float, dh: int, bs: int):
+    _flash_step(qe_ref, qo_ref,
+                lambda: (kp_ref[0], ks_ref[0], vp_ref[0], vs_ref[0]),
                 len_ref[pl.program_id(0)], win_ref[0], s32_ref, *refs,
-                softcap=softcap, dh=dh)
+                softcap=softcap, dh=dh, bs=bs)
 
 
-def _attn_decode_paged_kernel(bt_ref, len_ref, win_ref, s32_ref, qe_ref,
+def _attn_decode_paged_kernel(units_ref, len_ref, win_ref, s32_ref, qe_ref,
                               qo_ref, *refs, softcap: float, dh: int,
-                              n_sub: int):
+                              bs: int, n_sub: int):
     """Paged flash step: the grid's index maps already gathered the right
-    physical pages (via the prefetched block table), so the body only has
-    to stitch the ``n_sub`` page-sized sub-blocks back into one logical
+    physical pages (via the prefetched unit table), so the body only has
+    to stitch the ``n_sub`` sub-blocks back into one logical
     (bs, Hkv*dh//2) key block.  Packed bytes concatenate before decode ==
     decode-then-concatenate (the Fig. 9 decode is element-wise per row)."""
-    del bt_ref  # consumed by the index maps
+    del units_ref  # consumed by the index maps
     kv, out = refs[:4 * n_sub], refs[4 * n_sub:]
 
     def cat(sub_refs):
         blocks = [r[0].astype(jnp.int32) for r in sub_refs]
         return blocks[0] if n_sub == 1 else jnp.concatenate(blocks, axis=0)
 
-    kp = cat(kv[0 * n_sub:1 * n_sub])
-    ks = cat(kv[1 * n_sub:2 * n_sub])
-    vp = cat(kv[2 * n_sub:3 * n_sub])
-    vs = cat(kv[3 * n_sub:4 * n_sub])
-    _flash_step(qe_ref, qo_ref, kp, ks, vp, vs, len_ref[pl.program_id(0)],
-                win_ref[0], s32_ref, *out, softcap=softcap, dh=dh)
+    def load_kv():
+        return tuple(cat(kv[k * n_sub:(k + 1) * n_sub]) for k in range(4))
+
+    _flash_step(qe_ref, qo_ref, load_kv, len_ref[pl.program_id(0)],
+                win_ref[0], s32_ref, *out, softcap=softcap, dh=dh, bs=bs)
 
 
 @functools.partial(
@@ -288,17 +337,55 @@ def mixfp4_attn_decode(
     if sp != s:  # padded rows are masked by `kpos < lengths`
         kv = [jnp.pad(a, ((0, 0), (0, sp - s), (0, 0))) for a in kv]
 
-    w = hkv * dh2
-    kv_spec = pl.BlockSpec((1, bs, w), lambda i, j, *_: (i, j, 0))
-    sc_spec = pl.BlockSpec((1, bs, w // 8), lambda i, j, *_: (i, j, 0))
+    w, nb = hkv * dh2, sp // bs
+
+    def kv_map(i, j, len_ref, win_ref):
+        # the block clamped into row i's range: a step outside it names
+        # its neighbour's block, so the pipeline starts no DMA for it
+        lo, hi = key_block_range(len_ref[i], win_ref[0], bs, nb)
+        return i, jnp.clip(j, lo, hi), 0
+
+    kv_spec = pl.BlockSpec((1, bs, w), kv_map)
+    sc_spec = pl.BlockSpec((1, bs, w // 8), kv_map)
     (qe, qo), head = _split_queries(q, hkv)
     lengths, win, s32 = _scalars(lengths, window, k_scale32, v_scale32, b)
     oe, oo = _call(
         "mixfp4_attn_decode",
-        functools.partial(_attn_decode_kernel, softcap=softcap, dh=dh),
-        (b, sp // bs), 2, [kv_spec, sc_spec, kv_spec, sc_spec], (qe, qo),
+        functools.partial(_attn_decode_kernel, softcap=softcap, dh=dh,
+                          bs=bs),
+        (b, nb), 2, [kv_spec, sc_spec, kv_spec, sc_spec], (qe, qo),
         h, w, interpret, (lengths, win, s32), kv)
     return _merge_heads(oe, oo, head, hkv)
+
+
+def paged_key_block(max_pages: int, page_len: int, hkv: int, dh: int,
+                    bs: int | None = None) -> int:
+    """Key-block rows of the paged kernel over ``max_pages`` pages of
+    ``page_len`` rows: ``bs``, or the tuner's choice for that logical S,
+    made commensurate with the page so each key block is a whole number
+    of pages or a whole fraction of one (power-of-two page lengths always
+    are, for the tuner's power-of-two choices)."""
+    s_logical = max_pages * page_len
+    if bs is None:
+        from repro.kernels import tuning  # deferred: keep module deps flat
+        bs = tuning.select_attn_key_block(s_logical, hkv, dh)
+    bs = min(bs, max(s_logical, 1))
+    if bs >= page_len:
+        return bs - bs % page_len
+    return bs if page_len % bs == 0 else page_len
+
+
+def paged_kv_blocks(lengths, windows, max_pages: int, page_len: int,
+                    hkv: int, dh: int) -> int:
+    """Key blocks the paged kernel visits, with its own key-block choice,
+    over rows of ``lengths`` in one call per attention layer of
+    ``windows`` (host numpy: the serving engine's count of the kernel's
+    work, with no device read)."""
+    bs = paged_key_block(max_pages, page_len, hkv, dh)
+    first, last = key_block_range(
+        np.asarray(lengths)[None, :], np.asarray(windows)[:, None], bs,
+        -(-max_pages * page_len // bs), xp=np)
+    return int((last - first + 1).sum())
 
 
 @functools.partial(
@@ -324,14 +411,17 @@ def mixfp4_attn_decode_paged(
     K/V children are physical page slabs ``(P, page_len, Hkv, ...)`` and
     ``block_tables`` (B, max_pages) int32 maps each sequence's logical page
     order to slab rows (page 0 is the pool's trash page: unused table tail
-    entries point there and are masked by ``lengths``).  The table rides
+    entries point there and are masked by ``lengths``).  The table,
+    turned into the DMA units each grid step reads and clamped into each
+    row's key-block range (``_visited_units``), rides
     ``PrefetchScalarGridSpec`` scalar prefetch so the page gather happens
     in the BlockSpec index maps — per grid step the kernel DMAs only the
-    pages of that key block, and the body is the same ``_flash_step`` as
-    the fixed-slot kernel.  With ``bs`` equal to the fixed path's tuner
-    choice for the same logical S (the serving engine guarantees this by
-    requiring ``max_len % page_len == 0``), the paged output is
-    bitwise-identical to ``mixfp4_attn_decode`` on the gathered rows.
+    pages of that key block, none outside the range, and the body is the
+    same ``_flash_step`` as the fixed-slot kernel.  With ``bs`` equal to
+    the fixed path's tuner choice for the same logical S (the serving
+    engine guarantees this by requiring ``max_len % page_len == 0``), the
+    paged output is bitwise-identical to ``mixfp4_attn_decode`` on the
+    gathered rows.
     """
     b, h, dh = q.shape
     n_pages, page_len, hkv, dh2 = k_payload.shape
@@ -344,48 +434,35 @@ def mixfp4_attn_decode_paged(
 
     max_pages = block_tables.shape[1]
     s_logical = max_pages * page_len
-    if bs is None:
-        from repro.kernels import tuning  # deferred: keep module deps flat
-        bs = tuning.select_attn_key_block(s_logical, hkv, dh)
-    bs = min(bs, max(s_logical, 1))
-    # The grid needs bs and page_len commensurate so each key block is a
-    # whole number of (partial) pages; power-of-two page lengths always
-    # satisfy this for the tuner's power-of-two bs choices.
-    if bs >= page_len:
-        bs -= bs % page_len
-    elif page_len % bs:
-        bs = page_len
-
-    sp = -(-s_logical // bs) * bs
+    bs = paged_key_block(max_pages, page_len, hkv, dh, bs)
+    nb = -(-s_logical // bs)
+    sp = nb * bs
     if sp != s_logical:  # pad table columns with the trash page (masked)
         block_tables = jnp.pad(
             block_tables, ((0, 0), (0, sp // page_len - max_pages)))
-    block_tables = jnp.asarray(block_tables, jnp.int32)
+    # a key block is n_sub DMA units of `unit` rows: whole pages, or the
+    # page_len // unit slices of one page
+    unit = min(bs, page_len)
+    n_sub, upp = bs // unit, page_len // unit
+    lengths, win, s32 = _scalars(lengths, window, k_scale32, v_scale32, b)
+    units = _visited_units(jnp.asarray(block_tables, jnp.int32), lengths,
+                           win[0], bs, nb, n_sub, upp)
 
-    if bs >= page_len:
-        n_sub, rows = bs // page_len, page_len
-
-        def _page_map(t):
-            return lambda i, j, bt, *_: (bt[i, j * n_sub + t], 0, 0)
-
-        maps = [_page_map(t) for t in range(n_sub)]
-    else:
-        n_sub, rows = 1, bs
-        ipb = page_len // bs
-        maps = [lambda i, j, bt, *_: (bt[i, j // ipb], j % ipb, 0)]
+    def _unit_map(t):
+        return lambda i, j, units, *_: (units[i, j * n_sub + t], 0, 0)
 
     w = hkv * dh2
-    kv_specs = [pl.BlockSpec((1, rows, w), m) for m in maps]
-    sc_specs = [pl.BlockSpec((1, rows, w // 8), m) for m in maps]
-    kp, ks, vp, vs = (_flat_kv(a)
+    maps = [_unit_map(t) for t in range(n_sub)]
+    kv_specs = [pl.BlockSpec((1, unit, w), m) for m in maps]
+    sc_specs = [pl.BlockSpec((1, unit, w // 8), m) for m in maps]
+    kp, ks, vp, vs = (_flat_kv(a).reshape(n_pages * upp, unit, -1)
                       for a in (k_payload, k_scales, v_payload, v_scales))
     (qe, qo), head = _split_queries(q, hkv)
-    lengths, win, s32 = _scalars(lengths, window, k_scale32, v_scale32, b)
     oe, oo = _call(
         "mixfp4_attn_decode_paged",
         functools.partial(_attn_decode_paged_kernel, softcap=softcap,
-                          dh=dh, n_sub=n_sub),
-        (b, sp // bs), 3, [*kv_specs, *sc_specs, *kv_specs, *sc_specs],
-        (qe, qo), h, w, interpret, (block_tables, lengths, win, s32),
+                          dh=dh, bs=bs, n_sub=n_sub),
+        (b, nb), 3, [*kv_specs, *sc_specs, *kv_specs, *sc_specs],
+        (qe, qo), h, w, interpret, (units, lengths, win, s32),
         [kp] * n_sub + [ks] * n_sub + [vp] * n_sub + [vs] * n_sub)
     return _merge_heads(oe, oo, head, hkv)

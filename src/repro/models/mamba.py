@@ -313,6 +313,11 @@ class MambaLM:
     def n_attn_apps(self) -> int:
         return int(self._attn_flags()[0].sum())
 
+    def layer_windows(self) -> np.ndarray:
+        """The window of each application of the shared attention block:
+        all full (0), as ``_shared_block`` calls it."""
+        return np.zeros((self.n_attn_apps(),), np.int32)
+
     def _init_states(self, batch: int):
         cfg = self.cfg
         di, n = self.d_inner, cfg.ssm_state

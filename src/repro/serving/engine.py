@@ -81,11 +81,12 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import qtensor
 from repro.distributed import sharding as dist_sharding
+from repro.kernels.mixfp4_attn import paged_kv_blocks
 from repro.models.base import ArchConfig, Ctx, build_model, pack_projections
 from repro.serving.faults import InjectedFault, SystemClock
 from repro.serving.journal import JournalError, RequestJournal, replay
 from repro.serving.kvpool import KVPool
-from repro.serving.metrics import MetricsRegistry, span
+from repro.serving.metrics import MetricsRegistry, span, tracing
 from repro.serving.scheduler import ChunkedPrefillScheduler
 from repro.serving.watchdog import StepWatchdog
 
@@ -432,6 +433,8 @@ class ServeEngine:
                 (batch_size, max_len // kv_page_len), np.int32)
             self._slot_pages: list = [None] * batch_size
             self._copy_page = jax.jit(self._cow_copy)
+            # each attention layer's window, for serve.decode's kv_blocks
+            self._kv_windows = self.model.layer_windows()
         else:
             self.kv_pool = None
             if self.kv_quant == "mixfp4":
@@ -1770,13 +1773,26 @@ class ServeEngine:
         backoff retry off the fused path), transients back off and retry,
         and a fatal fault quarantines its victim's slot, then decodes the
         survivors.  Its span records the rows decoded, the cache rows the
-        occupied slots hold, and the pool pages reserved for them."""
-        pool = {} if self.kv_pool is None else {
-            "pages": self.kv_pool.pages_active}
+        occupied slots hold, and, paged, the pool pages reserved for them
+        and the key blocks the attention kernel visits."""
+        pool = {}
+        if self.kv_pool is not None:
+            pool["pages"] = self.kv_pool.pages_active
+            if tracing():
+                pool["kv_blocks"] = self._kv_blocks()
         held = sum(int(self.lengths[i]) for i, r in enumerate(self.slots)
                    if r is not None)
         with span("decode", rows=len(active), held_tokens=held, **pool):
             return self._decode_with_faults(toks, active)
+
+    def _kv_blocks(self) -> int:
+        """Key blocks the paged attention kernel visits in this decode
+        step, over every lane and attention layer: a lane reads its length
+        + 1 rows (an idle lane one), each layer within its own window."""
+        page_len = self.kv_pool.page_len
+        return paged_kv_blocks(self.lengths + 1, self._kv_windows,
+                               self.max_len // page_len, page_len,
+                               self.cfg.n_kv_heads, self.cfg.dh)
 
     def _decode_with_faults(self, toks, active):
         self._step_poison = set()

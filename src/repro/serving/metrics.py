@@ -35,7 +35,7 @@ from typing import Iterable
 from jax.profiler import TraceAnnotation
 
 __all__ = ["MetricsRegistry", "Histogram", "render_prometheus", "percentile",
-           "span"]
+           "span", "tracing"]
 
 
 def span(name: str, **args) -> TraceAnnotation:
@@ -43,6 +43,12 @@ def span(name: str, **args) -> TraceAnnotation:
     recorded with it when it opens; pass only host values already at hand
     (reading a device value here would add a sync to the step)."""
     return TraceAnnotation("serve." + name, **args)
+
+
+def tracing() -> bool:
+    """Whether a profiler trace is recording spans now: an arg that costs
+    host work to compute is worth computing only then."""
+    return TraceAnnotation.is_enabled()
 
 
 def percentile(samples: Iterable[float], q: float) -> float:

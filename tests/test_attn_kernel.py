@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import mixfp4_attn, ops, ref
 from repro.models import base
 
 
@@ -200,3 +200,170 @@ def test_attn_decode_paged_trash_page_masked():
     out = ops.attn_decode_paged(q, kpp, kps, vpp, vps, bt_trash, lengths,
                                 interpret=True, bs=16)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(base_out))
+
+
+# ---------------------------------------------------------------------------
+# Key-block range: each row visits only the blocks that hold a key it
+# attends to (no DMA, no decode of the rest)
+# ---------------------------------------------------------------------------
+def _paged_and_fixed(b, s, hkv, g, dh, window, page_len, bs, lengths,
+                     softcap=0.0, seed=0):
+    """(paged, fixed) outputs over the same logical rows, and the inputs."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(keys[0], (b, hkv * g, dh), jnp.float32)
+    _, kp, ks = _packed_kv(keys[1], b, s, hkv, dh)
+    _, vp, vs = _packed_kv(keys[2], b, s, hkv, dh)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    fixed = ops.attn_decode_packed(q, kp, ks, vp, vs, lengths,
+                                   window=window, softcap=softcap,
+                                   interpret=True, bs=bs)
+    kpp, kps, bt = _page_slabs(kp, ks, page_len, seed=seed)
+    vpp, vps, _ = _page_slabs(vp, vs, page_len, seed=seed)
+    paged = ops.attn_decode_paged(q, kpp, kps, vpp, vps, bt, lengths,
+                                  window=window, softcap=softcap,
+                                  interpret=True, bs=bs)
+    return paged, fixed, (q, kp, ks, vp, vs, lengths)
+
+
+EDGE_CASES = [
+    # (s, page_len, bs): bs == page, bs < page (sub-page), bs > page
+    (64, 16, 16),
+    (64, 32, 16),
+    (96, 16, 32),
+]
+
+
+@pytest.mark.parametrize("s,page_len,bs", EDGE_CASES)
+@pytest.mark.parametrize("window", [0, 5])
+def test_attn_decode_paged_bitwise_at_block_edges(s, page_len, bs, window):
+    """Lengths on either side of a key-block edge (1, bs-1, bs, bs+1, S):
+    the last block a row visits is partly or wholly valid, and the paged
+    kernel stays bitwise the fixed-slot one and close to the oracle."""
+    lengths = [1, bs - 1, bs, bs + 1, s]
+    paged, fixed, args = _paged_and_fixed(5, s, 2, 2, 32, window, page_len,
+                                          bs, lengths, seed=s + bs)
+    np.testing.assert_array_equal(np.asarray(paged), np.asarray(fixed))
+    want = ref.ref_attn_decode_packed(*args, window=window)
+    np.testing.assert_allclose(np.asarray(fixed), np.asarray(want),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("page_len,bs", [(16, 16), (32, 16), (16, 32)])
+def test_attn_decode_window_skips_whole_blocks(page_len, bs):
+    """A window that starts several key blocks into the row: the blocks
+    before it are skipped, and the result is the oracle's and bitwise
+    the fixed-slot kernel's."""
+    window, lengths = 20, [100, 128, 57, 21]
+    assert all(l - window >= bs for l in lengths[:3])
+    paged, fixed, args = _paged_and_fixed(4, 128, 2, 2, 32, window,
+                                          page_len, bs, lengths,
+                                          softcap=30.0, seed=bs)
+    np.testing.assert_array_equal(np.asarray(paged), np.asarray(fixed))
+    want = ref.ref_attn_decode_packed(*args, window=window, softcap=30.0)
+    np.testing.assert_allclose(np.asarray(fixed), np.asarray(want),
+                               atol=1e-5)
+
+
+def _random_tail(a, lengths, bs, scale_bytes: bool, rng):
+    """``a`` (B, S, ...) with every row at or past each length replaced by
+    random non-zero bytes.  Scale bytes in the last block a row visits
+    avoid E4M3's NaN code (0x7F under the type bit): those rows are
+    decoded and multiplied by a zero weight, and only the quantizer
+    writes them.  Blocks wholly past the length may hold NaN codes: the
+    kernel never decodes them."""
+    a = np.array(a)
+    for i, n in enumerate(lengths):
+        tail = rng.integers(1, 256, a[i, n:].shape).astype(np.uint8)
+        if scale_bytes:
+            edge = min(-(-n // bs) * bs, a.shape[1]) - n
+            part = tail[:edge]
+            part[(part & 0x7F) == 0x7F] -= 1
+            tail[edge:].flat[::7] = 0x7F
+        a[i, n:] = tail
+    return jnp.asarray(a)
+
+
+@pytest.mark.parametrize("page_len,bs", [(16, 16), (32, 16), (16, 32)])
+def test_attn_decode_ignores_rows_past_length(page_len, bs):
+    """Rows past each length filled with random bytes read bitwise as if
+    they were zeros, on both kernels: rows inside a visited block carry a
+    zero weight, and blocks past the last one are never decoded."""
+    b, s, hkv, g, dh = 4, 128, 2, 2, 32
+    lengths = [1, bs + 3, 3 * bs, 70]
+    keys = jax.random.split(jax.random.PRNGKey(21), 3)
+    q = jax.random.normal(keys[0], (b, hkv * g, dh), jnp.float32)
+    _, kp, ks = _packed_kv(keys[1], b, s, hkv, dh)
+    _, vp, vs = _packed_kv(keys[2], b, s, hkv, dh)
+    rng = np.random.default_rng(bs + page_len)
+    valid = (np.arange(s)[None, :] < np.asarray(lengths)[:, None])
+    zeros = [jnp.where(valid[..., None, None], a, 0)
+             for a in (kp, ks, vp, vs)]
+    junk = [_random_tail(a, lengths, bs, k % 2 == 1, rng)
+            for k, a in enumerate((kp, ks, vp, vs))]
+    ln = jnp.asarray(lengths, jnp.int32)
+    outs = []
+    for kv in (zeros, junk):
+        fixed = ops.attn_decode_packed(q, *kv, ln, interpret=True, bs=bs)
+        slabs = [_page_slabs(kv[k], kv[k + 1], page_len, seed=5)
+                 for k in (0, 2)]
+        paged = ops.attn_decode_paged(q, *slabs[0][:2], *slabs[1][:2],
+                                      slabs[0][2], ln, interpret=True,
+                                      bs=bs)
+        outs.append((np.asarray(fixed), np.asarray(paged)))
+    assert np.isfinite(outs[0][0]).all()
+    for got in outs[1]:
+        np.testing.assert_array_equal(got, outs[0][0])
+    np.testing.assert_array_equal(outs[0][1], outs[0][0])
+
+
+@pytest.mark.parametrize("bs", [16, 32, 48])
+def test_key_block_range_is_the_masked_blocks(bs):
+    """Brute force over lengths and windows: a block lies in
+    ``[first, last]`` iff some key in it passes ``_flash_step``'s mask
+    (row below the length, and inside the window); a row of no keys
+    reads (0, 0).  numpy and traced int32 give the same range."""
+    nb = 5
+    kpos = np.arange(nb * bs)
+    lengths = np.arange(-1, nb * bs + 1)
+    windows = np.array([0, 1, 2, bs - 1, bs, bs + 1, 2 * bs + 3, 10 * bs])
+    ln, wn = np.meshgrid(lengths, windows, indexing="ij")
+    first, last = mixfp4_attn.key_block_range(ln, wn, bs, nb, xp=np)
+    jfirst, jlast = jax.jit(
+        lambda a, w: mixfp4_attn.key_block_range(a, w, bs, nb))(ln, wn)
+    np.testing.assert_array_equal(np.asarray(jfirst), first)
+    np.testing.assert_array_equal(np.asarray(jlast), last)
+    for (n, w), lo, hi in zip(np.ndindex(ln.shape), first.flat, last.flat):
+        length, win = lengths[n], windows[w]
+        keep = (kpos < length) & ((kpos > length - 1 - win) | (win <= 0))
+        hit = keep.reshape(nb, bs).any(axis=1)
+        if length <= 0:
+            assert (lo, hi) == (0, 0) and not hit.any()
+            continue
+        np.testing.assert_array_equal(
+            hit, (np.arange(nb) >= lo) & (np.arange(nb) <= hi),
+            err_msg=f"length {length} window {win}")
+
+
+@pytest.mark.parametrize("page_len,max_pages", [(16, 64), (16, 8), (64, 4)])
+def test_paged_kv_blocks_counts_the_blocks_the_kernel_reads(page_len,
+                                                            max_pages):
+    """The host count of key blocks visited equals the number of distinct
+    key blocks the paged kernel's unit table names per row (each table
+    entry a distinct page), for whole-page and sub-page key blocks, over
+    lengths at the block edges and windows that drop whole blocks."""
+    hkv, dh = 2, 32
+    bs = mixfp4_attn.paged_key_block(max_pages, page_len, hkv, dh)
+    s = max_pages * page_len
+    nb = -(-s // bs)
+    unit = min(bs, page_len)
+    n_sub, upp = bs // unit, page_len // unit
+    lengths = np.array([0, 1, bs - 1, bs, bs + 1, s // 2, s - 1, s])
+    tables = jnp.asarray(1 + np.arange(len(lengths) * max_pages).reshape(
+        len(lengths), max_pages), jnp.int32)
+    for win in (0, 1, bs + 3, 2 * bs + 1):
+        units = np.asarray(mixfp4_attn._visited_units(
+            tables, jnp.asarray(lengths, jnp.int32), jnp.int32(win), bs, nb,
+            n_sub, upp)).reshape(len(lengths), nb, n_sub)
+        want = sum(len({tuple(u) for u in row}) for row in units)
+        assert mixfp4_attn.paged_kv_blocks(
+            lengths, [win], max_pages, page_len, hkv, dh) == want, win

@@ -163,3 +163,23 @@ def test_attention_paged_compiles(one_chip):
         *_kv(one_chip, (pages, page)),
         _sds(one_chip, (b, s // page), jnp.int32),
         _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (), jnp.int32))
+
+
+def test_attention_paged_compiles_at_phi3_cell_widths(one_chip):
+    """The paged kernel as the phi3-medium-14b chat benchmark runs it: 32
+    lanes, 40 query and 10 KV heads of 128, a 3072-page pool of 16 rows,
+    256 pages a row, so the tuner's 512-row key block spans 32 pages and
+    each K/V operand has 32 page-sized specs, whose index maps read the
+    unit table clamped into each row's key-block range."""
+    b, h, hkv, dh, page, max_pages, pool = 32, 40, 10, 128, 16, 256, 3072
+    assert mixfp4_attn.paged_key_block(max_pages, page, hkv, dh) == 512
+    kv = [_sds(one_chip, (pool, page, hkv, dh // 2), jnp.uint8),
+          _sds(one_chip, (pool, page, hkv, dh // 16), jnp.uint8)] * 2
+    _compile_native(
+        "mixfp4_attn_decode_paged",
+        lambda q, kp, ks, vp, vs, bt, ln, w:
+        mixfp4_attn.mixfp4_attn_decode_paged(
+            q, kp, ks, vp, vs, bt, ln, window=w, interpret=False),
+        _sds(one_chip, (b, h, dh), jnp.bfloat16), *kv,
+        _sds(one_chip, (b, max_pages), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (), jnp.int32))

@@ -9,6 +9,8 @@ bitwise chunked==whole-prompt property is in test_scheduler_props.py
 (hypothesis); a concrete multi-request stream-equality case rides here
 so bare environments still pin it.
 """
+import warnings
+
 import numpy as np
 import pytest
 
@@ -233,3 +235,100 @@ def test_cancel_mid_chunked_prefill_releases_slot_and_pages(small_cfg,
     assert req2.state is RequestState.FINISHED
     assert eng.kv_pool.prefix_hits == 0
     assert eng.pool_report()["pages_active"] == 0
+
+
+# ---------------------------------------------------------------------------
+# serve.decode's kv_blocks: the key blocks the paged attention kernel visits
+# ---------------------------------------------------------------------------
+def test_decode_span_counts_the_key_blocks_visited(tmp_path):
+    """On a CPU trace of a paged engine whose rows span two 512-row key
+    blocks, with one windowed and one global layer, every ``serve.decode``
+    span carries ``kv_blocks``: the range helper's count for the lengths
+    the engine decoded at (each lane reads length + 1 rows, idle lanes
+    one).  One prompt's window has moved past its first block; the other
+    crosses the block edge as it decodes."""
+    from jax.profiler import ProfileData
+
+    from repro.kernels.mixfp4_attn import paged_key_block, paged_kv_blocks
+
+    cfg = ArchConfig(name="kv-blocks-test", family="dense", n_layers=2,
+                     d_model=64, n_heads=2, n_kv_heads=2, d_ff=128,
+                     vocab=64, attn_chunk=64, window=100,
+                     local_global_period=2,
+                     quant=QuantConfig(method="mixfp4"))
+    params = build_model(cfg).init(jax.random.PRNGKey(0))[0]
+    eng = ServeEngine(cfg, params, batch_size=3, max_len=1024,
+                      kv_quant="mixfp4", kv_pool=80, kv_page_len=16,
+                      prefill_chunk=256)
+    decoded_at = []
+    decode = eng._decode_with_faults
+
+    def spy(toks, active):
+        decoded_at.append(eng.lengths.copy())
+        return decode(toks, active)
+
+    eng._decode_with_faults = spy
+    reqs = [Request(uid=uid, prompt=np.arange(n, dtype=np.int32) % 61 + 1,
+                    max_new_tokens=3)
+            for uid, n in enumerate([700, 511])]
+    for r in reqs:
+        eng.add_request(r)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0        # Python call tracing slows the host
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        _drain(eng, reqs)
+    assert all(r.state is RequestState.FINISHED for r in reqs)
+
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    with warnings.catch_warnings():   # the stats type warns when first used
+        warnings.simplefilter("ignore", DeprecationWarning)
+        spans = sorted((ev.start_ns, dict(ev.stats))
+                       for plane in ProfileData.from_file(str(path)).planes
+                       if plane.name.startswith("/host:")
+                       for line in plane.lines for ev in line.events
+                       if ev.name == "serve.decode")
+    got = [args["kv_blocks"] for _, args in spans]
+    bs = paged_key_block(1024 // 16, 16, cfg.n_kv_heads, cfg.dh)
+    windows = eng.model.layer_windows()
+    assert bs == 512 and list(windows) == [100, 0]
+    assert got == [paged_kv_blocks(lengths + 1, windows, 1024 // 16, 16,
+                                   cfg.n_kv_heads, cfg.dh)
+                   for lengths in decoded_at]
+    # by hand, (windowed + global layer) blocks per lane: the 700-row
+    # prompt reads its second block alone within its window and both
+    # blocks globally (1 + 2); the 511-row prompt reads one block in each
+    # layer (2) until its query reaches row 512 (2 + 2); idle lanes read
+    # one row (2)
+    assert got == [3 + 2 + 2, 3 + 2 + 2, 2 + 2 + 2, 2 + 4 + 2]
+
+
+def test_decode_span_reads_kv_blocks_only_in_a_trace():
+    """Out of a trace the decode step computes no ``kv_blocks``: nothing
+    would record it."""
+    cfg = ArchConfig(name="kv-blocks-off", family="dense", n_layers=1,
+                     d_model=64, n_heads=2, n_kv_heads=2, d_ff=128,
+                     vocab=64, attn_chunk=64,
+                     quant=QuantConfig(method="mixfp4"))
+    params = build_model(cfg).init(jax.random.PRNGKey(0))[0]
+    eng = ServeEngine(cfg, params, batch_size=2, max_len=64,
+                      kv_quant="mixfp4", kv_pool=16, kv_page_len=16)
+    calls = []
+    eng._kv_blocks = lambda: calls.append(1) or 0
+    req = Request(uid=0, prompt=np.arange(5, dtype=np.int32) + 1,
+                  max_new_tokens=3)
+    eng.add_request(req)
+    _drain(eng, [req])
+    assert req.state is RequestState.FINISHED and calls == []
+
+
+def test_hybrid_windows_cover_each_shared_attention_application():
+    """The hybrid names one (full, 0) window per application of its shared
+    attention block, as a transformer names one per layer, so the engine
+    counts the paged kernel's blocks over either family alike."""
+    cfg = ArchConfig(name="kv-blocks-hyb", family="hybrid", n_layers=4,
+                     d_model=64, vocab=64, n_heads=2, n_kv_heads=2,
+                     d_ff=128, ssm_state=8, ssm_expand=2, ssm_version=2,
+                     ssm_head_dim=32, attn_period=2, attn_chunk=64,
+                     quant=QuantConfig(method="mixfp4"))
+    model = build_model(cfg)
+    assert list(model.layer_windows()) == [0] * model.n_attn_apps() == [0, 0]
